@@ -3,7 +3,7 @@
 from __future__ import annotations
 
 import time
-from typing import Callable
+from typing import Callable, List
 
 import jax
 import jax.numpy as jnp
@@ -11,16 +11,17 @@ import numpy as np
 
 from repro.core.tree import XMRTree
 from repro.data.xmr_data import XMRShape, benchmark_queries
-from repro.sparse import random_sparse_csc
+from repro.sparse import CSC, random_sparse_csc
 from repro.trees.cluster import build_tree_structure
 
 
-def build_benchmark_tree(shape: XMRShape, branching: int,
-                         rng: np.random.Generator,
-                         *, upper_nnz: int = 64,
-                         sibling_overlap: float = 0.8) -> XMRTree:
-    """Random model at the dataset's dimensions (latency depends only on the
-    sparsity structure, not learned values — see data/xmr_data.py)."""
+def build_benchmark_weights(shape: XMRShape, branching: int,
+                            rng: np.random.Generator,
+                            *, upper_nnz: int = 64,
+                            sibling_overlap: float = 0.8) -> List[CSC]:
+    """Random per-level CSC weights at the dataset's dimensions (latency
+    depends only on the sparsity structure, not learned values — see
+    data/xmr_data.py)."""
     struct = build_tree_structure(shape.L, branching)
     weights = []
     for size in struct.level_sizes:
@@ -30,7 +31,15 @@ def build_benchmark_tree(shape: XMRShape, branching: int,
                               sibling_groups=branching,
                               sibling_overlap=sibling_overlap)
         )
-    return XMRTree.from_weight_matrices(weights, branching)
+    return weights
+
+
+def build_benchmark_tree(shape: XMRShape, branching: int,
+                         rng: np.random.Generator, **kw) -> XMRTree:
+    """:func:`build_benchmark_weights` packed into a served tree."""
+    return XMRTree.from_weight_matrices(
+        build_benchmark_weights(shape, branching, rng, **kw), branching
+    )
 
 
 def time_fn(fn: Callable, *args, warmup: int = 2, iters: int = 5,

@@ -39,7 +39,8 @@ def main() -> None:
 
     print("3) serving with each masked-matmul method:")
     ref_labels = None
-    for method in ("vanilla", "mscm_dense", "mscm_searchsorted", "mscm_pallas"):
+    for method in ("vanilla", "mscm_dense", "mscm_searchsorted",
+                   "mscm_pallas_grouped"):
         scores, labels = model.predict(xi, xv, beam=16, topk=5, method=method)
         t0 = time.time()
         for _ in range(3):

@@ -37,6 +37,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))  # benchmarks/
 from benchmarks.common import build_benchmark_tree
+from repro.compile_cache import enable_compile_cache
 from repro.data.xmr_data import XMRShape, benchmark_queries
 from repro.serving import (
     BatchPolicy,
@@ -53,6 +54,7 @@ from repro.serving.config import QUANT_TIERS
 
 
 def main() -> None:
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     ap = argparse.ArgumentParser()
     ap.add_argument("--queries", type=int, default=256)
     ap.add_argument("--beam", type=int, default=10)
@@ -101,9 +103,10 @@ def main() -> None:
         return
 
     print("\n== batch setting (Table 4 panel) ==")
-    # A non-exact tier forces the quantized kernel, so the per-method
-    # panel collapses to the single tier method.
-    methods = (("mscm_dense", "mscm_searchsorted", "vanilla")
+    # "auto" is the backend's serving path (the grouped Pallas kernel on a
+    # TPU, mscm_dense elsewhere). A non-exact tier forces the quantized
+    # kernel, so the per-method panel collapses to the single tier method.
+    methods = (("auto", "mscm_searchsorted", "vanilla")
                if args.tier == "exact" else ("auto",))
     for method in methods:
         eng = XMRServingEngine(
@@ -117,7 +120,7 @@ def main() -> None:
         scores, labels = eng.serve_batch(queries)
         wall = time.time() - t0
         s = eng.latency_summary()["amortized"]
-        print(f"{method:20s} amortized {s['avg_ms_per_query']:7.3f} ms/q "
+        print(f"{eng.method:20s} amortized {s['avg_ms_per_query']:7.3f} ms/q "
               f"over {s['queries']} queries "
               f"({wall:.1f}s wall; per-query percentiles are an online-"
               f"setting metric)")
@@ -125,8 +128,7 @@ def main() -> None:
     print("\n== online setting (async micro-batching) ==")
     eng = XMRServingEngine(
         tree, ServeConfig(
-            beam=args.beam, topk=10,
-            method="mscm_dense" if args.tier == "exact" else "auto",
+            beam=args.beam, topk=10, method="auto",
             ell_width=256, max_batch=64,
             quant=QuantConfig(tier=args.tier)))
     eng.warmup_buckets(shape.d, args.max_batch)

@@ -29,25 +29,6 @@ from repro.core.beam import NEG_INF, beam_step, topk_canonical
 from repro.core.tree import TreeLayerArrays, XMRTree
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-portable ``jax.shard_map``.
-
-    Public API from jax 0.6; older versions expose it as
-    ``jax.experimental.shard_map.shard_map`` with the replication check named
-    ``check_rep`` instead of ``check_vma``.
-    """
-    sm = getattr(jax, "shard_map", None)
-    kw = {}
-    if sm is not None:
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def shard_leaf_level(tree: XMRTree, mesh: Mesh):
     """Device-put the leaf level sharded over 'model', upper levels replicated."""
     leaf = tree.layers[-1]
@@ -87,7 +68,7 @@ def sharded_infer(
     )
 
     @functools.partial(
-        shard_map_compat,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             P("data", None), P("data", None),

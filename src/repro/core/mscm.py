@@ -35,6 +35,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+#: Matmul precision of every f32 dot on the serving path. A TPU's default
+#: rounds f32 operands to bf16 (~1e-3 relative error); HIGHEST keeps them
+#: f32, which the exactness contract (core.tree.METHODS) needs.
+F32 = jax.lax.Precision.HIGHEST
+
 
 def scatter_dense(x_idx: jax.Array, x_val: jax.Array, d: int) -> jax.Array:
     """Scatter ELL queries into a dense [n, d+1] lookup table.
@@ -59,7 +64,7 @@ def mscm_dense_lookup(
     """Dense-lookup MSCM: gather query values at chunk rows, contract."""
     r = rows[block_c]                                   # [A, R]
     xg = x_dense[block_q[:, None], r]                   # [A, R]  (gather)
-    return jnp.einsum("ar,arb->ab", xg, vals[block_c])  # [A, B]
+    return jnp.einsum("ar,arb->ab", xg, vals[block_c], precision=F32)  # [A, B]
 
 
 def gather_query_rows(
@@ -98,7 +103,7 @@ def mscm_searchsorted(
     pos_c = jnp.minimum(pos, q - 1)
     hit = (jnp.take_along_axis(xi, pos_c, axis=1) == r) & (r < d)
     xg = jnp.where(hit, jnp.take_along_axis(xv, pos_c, axis=1), 0.0)
-    return jnp.einsum("ar,arb->ab", xg, vals[block_c])
+    return jnp.einsum("ar,arb->ab", xg, vals[block_c], precision=F32)
 
 
 def vanilla_columns(

@@ -4,8 +4,8 @@ An :class:`XMRTree` holds one :class:`~repro.core.chunked.ChunkedLayer` per
 tree level (plus the vanilla per-column layout for the baseline method) as
 device arrays. ``infer`` runs the full beam search; the per-level masked
 matmul dispatches to any of the MSCM variants or the Pallas kernels, and all
-of them return *identical* rankings — the paper's "free of charge" property,
-pinned by tests.
+of them return the same rankings — the paper's "free of charge" property,
+pinned by tests under the contract stated above ``METHODS``.
 
 Label layout convention: nodes at level l are numbered so that the children
 of node p are [p*B, (p+1)*B) at level l+1 — chunk id == parent id, which is
@@ -27,11 +27,19 @@ from repro.core.beam import NEG_INF, beam_select, combine_scores
 from repro.core.chunked import ChunkedLayer, ColumnELLLayer
 from repro.sparse.csr import CSC
 
-# Masked-matmul method selection — every exact entry returns *identical*
-# rankings (the paper's "free of charge" property, pinned by tests); they
-# differ only in how the traversal maps to hardware. The one exception is
-# the quantized tier's method (suffix ``_q``), which is exact *given its
-# compressed weights* but approximate against the f32 tree:
+# Masked-matmul method selection. The exactness contract:
+#   * within one method, results are bitwise identical across batch sizes,
+#     topologies (sharded / partitioned / pipelined / fleet) and beam tiers;
+#   * across the exact methods, labels are identical and scores agree to a
+#     few f32 ulp (``assert_allclose`` at ``rtol`` ~1e-6): each method sums
+#     the R-term chunk dot products in its own order (XLA einsum, Pallas
+#     tile matmul, per-column dots), so the last bit may differ. Labels can
+#     only differ where two candidates' scores lie within that tolerance.
+#     Both need every f32 dot at ``core.mscm.F32`` (HIGHEST): a TPU's
+#     default precision rounds the operands to bf16.
+# The methods differ only in how the traversal maps to hardware. The one
+# exception is the quantized tier's method (suffix ``_q``), which is exact
+# *given its compressed weights* but approximate against the f32 tree:
 #
 #   vanilla               per-column sparse dots (paper Alg. 4 baseline).
 #                         Correctness oracle; B× the traversal work.
@@ -45,7 +53,9 @@ from repro.sparse.csr import CSC
 #   mscm_pallas           Pallas fused kernel: one [1,R]×[R,B] contraction
 #                         per block, in-kernel VMEM gather, chunk-sorted grid
 #                         so each chunk tile is DMA'd once (paper Alg. 3).
-#                         Best online/small-batch TPU path for d ≤ ~1M.
+#                         Interpret mode only: Mosaic refuses its in-kernel
+#                         1-D gather, so on TPU it raises NotImplementedError
+#                         (for d ≤ ~1M; larger d takes the pregather kernel).
 #   mscm_pallas_pregather Pallas pregather kernel: XLA gathers query rows in
 #                         HBM, kernel streams [1,R]×[R,B]. The huge-d TPU
 #                         path (enterprise d = 4M).
@@ -59,8 +69,9 @@ from repro.sparse.csr import CSC
 #   mscm_pallas_grouped_q the grouped kernel over *quantized* chunk tiles
 #                         (int8/fp8 + per-column scales, repro.quant):
 #                         dequantize-in-register before the tile matmul.
-#                         The one approximate member — bitwise-identical to
-#                         mscm_pallas_grouped on the *dequantized* weights,
+#                         The one approximate member — bitwise-identical
+#                         (interpret mode) to mscm_pallas_grouped on the
+#                         *dequantized* weights,
 #                         but the weights themselves carry quantization
 #                         error (measured contract, benchmarks/bench_quant).
 METHODS = (
@@ -261,8 +272,8 @@ class XMRTree:
 
         ``method`` picks the masked-matmul backend (see the table above the
         ``METHODS`` tuple); ``qt`` is the query-tile height of the grouped
-        Pallas kernel (ignored by other methods). All methods return
-        identical rankings.
+        Pallas kernel (ignored by other methods). All methods return the
+        same rankings (see the contract above ``METHODS``).
 
         ``init_parent_ids``/``init_scores`` (int32/f32 ``[n, b]``) start the
         search from an externally-computed beam instead of the root — the

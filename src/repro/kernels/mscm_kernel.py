@@ -27,13 +27,18 @@ Kernels
                reference grouping used by tests/benchmark accounting.
 
 Alignment notes (TPU target; interpret mode ignores these):
+* Mosaic requires the last two dims of every block to be multiples of
+  (8, 128) or equal to the array's own; per-row operands therefore carry a
+  unit middle dim (``[A, 1, R]``, ``[T, QT, 1]``) instead of a ``(1, R)``
+  block over ``[A, R]``.
 * R is padded to a multiple of 8 by ``ChunkedLayer.from_csc`` (f32 sublanes).
 * B is the lane dimension of the chunk tile; B < 128 underutilizes lanes —
-  the grouped kernel's tiles put QT on sublanes to compensate, and the
-  pack-G-chunks-per-tile variant is evaluated in EXPERIMENTS §Perf.
-* The in-kernel gather (``jnp.take``) lowers to a VMEM dynamic gather; the
-  fused kernel therefore requires d+1 ≤ ~1M f32 elements (4 MB) per query
-  row. ``ops.choose_kernel`` enforces this bound.
+  the grouped kernel's tiles put QT on sublanes to compensate. XLA stores a
+  ``[C, R, B<128]`` array in a lane-dense layout and copies it into the
+  kernel's padded ``[R, 128]`` tiling on every call.
+* The fused kernel's in-kernel 1-D gather (``jnp.take``) does not lower on
+  Mosaic ("Only 2D gather is supported"): it runs in interpret mode only,
+  and ``ops.mscm_pallas`` refuses it on a TPU backend.
 """
 
 from __future__ import annotations
@@ -47,6 +52,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.mscm import F32
+
 
 # ---------------------------------------------------------------------------
 # fused: in-kernel gather from a VMEM-resident dense query row
@@ -59,6 +66,7 @@ def _fused_body(bq_ref, bc_ref, x_ref, rows_ref, vals_ref, out_ref):
     acc = jax.lax.dot_general(
         xg[None, :], vals_ref[0],
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=F32,
         preferred_element_type=jnp.float32,
     )                                                    # [1, B]
     out_ref[0, :] = acc[0]
@@ -101,11 +109,12 @@ def mscm_fused(
 def _pregather_body(bc_ref, xg_ref, vals_ref, out_ref):
     del bc_ref
     acc = jax.lax.dot_general(
-        xg_ref[...], vals_ref[0],
+        xg_ref[0], vals_ref[0],
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=F32,
         preferred_element_type=jnp.float32,
-    )
-    out_ref[...] = acc
+    )                                                    # [1, B]
+    out_ref[0] = acc
 
 
 def mscm_pregather(
@@ -117,21 +126,24 @@ def mscm_pregather(
 ) -> jax.Array:
     a, r = xg.shape
     c, _, b = vals.shape
+    # Rows ride as [A, 1, R] / [A, 1, B] so every block's last two dims equal
+    # the array's (a (1, R) block over [A, R] breaks Mosaic's (8, 128) rule).
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(a,),
         in_specs=[
-            pl.BlockSpec((1, r), lambda i, bc: (i, 0)),
+            pl.BlockSpec((1, 1, r), lambda i, bc: (i, 0, 0)),
             pl.BlockSpec((1, r, b), lambda i, bc: (bc[i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, b), lambda i, bc: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, b), lambda i, bc: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _pregather_body,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((a, b), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((a, 1, b), jnp.float32),
         interpret=interpret,
-    )(block_c, xg, vals)
+    )(block_c, xg[:, None, :], vals)
+    return out.reshape(a, b)
 
 
 # ---------------------------------------------------------------------------
@@ -175,12 +187,13 @@ def _grouped_body(tc_ref, xg_ref, ps_ref, vals_ref, out_ref, *, mode):
     acc = jax.lax.dot_general(
         xg_ref[0], vals_ref[0],
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=F32,
         preferred_element_type=jnp.float32,
     )                                                    # [QT, B]
     if mode == "prod":
-        acc = jax.nn.sigmoid(acc) * ps_ref[0][:, None]
+        acc = jax.nn.sigmoid(acc) * ps_ref[0]            # ps: [QT, 1]
     elif mode == "logsum":
-        acc = jax.nn.log_sigmoid(acc) + ps_ref[0][:, None]
+        acc = jax.nn.log_sigmoid(acc) + ps_ref[0]
     out_ref[0] = acc
 
 
@@ -214,12 +227,14 @@ def mscm_grouped(
                 "parent_scores (zeros would silently flatten every score)"
             )
         parent_scores = jnp.zeros((t, qt), jnp.float32)
+    # Parent scores ride as [T, QT, 1]: a (1, QT) block over [T, QT] breaks
+    # Mosaic's rule that a block's last two dims be (8k, 128k) or full.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(t,),
         in_specs=[
             pl.BlockSpec((1, qt, r), lambda i, tc: (i, 0, 0)),
-            pl.BlockSpec((1, qt), lambda i, tc: (i, 0)),
+            pl.BlockSpec((1, qt, 1), lambda i, tc: (i, 0, 0)),
             pl.BlockSpec((1, r, b), lambda i, tc: (tc[i], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, qt, b), lambda i, tc: (i, 0, 0)),
@@ -229,4 +244,4 @@ def mscm_grouped(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, qt, b), jnp.float32),
         interpret=interpret,
-    )(tile_chunk, xg_tiles, parent_scores, vals)
+    )(tile_chunk, xg_tiles, parent_scores[..., None], vals)

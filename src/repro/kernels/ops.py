@@ -1,10 +1,8 @@
 """Jitted wrappers around the MSCM Pallas kernels.
 
-On CPU (this container) the kernels run with ``interpret=True`` — the kernel
-body executes in Python for correctness validation; TPU is the compile
-target. ``interpret=None`` auto-detects from the backend; the
-``MSCM_FORCE_INTERPRET`` environment variable (``1``/``0``) overrides the
-auto-detection so CI can pin interpret mode explicitly.
+``interpret=None`` follows the backend: on a TPU the kernels are compiled by
+Mosaic; on any other backend (the CPU test runs) the kernel body executes in
+the Pallas interpreter, for correctness only. Nothing else switches it.
 
 The grouped path is fully device-resident: :func:`group_blocks_device`
 derives the chunk-major query tiles *inside* the jit (no host round-trip),
@@ -15,7 +13,6 @@ epilogue, top-k — compiles as one XLA program (paper §4, Alg. 3).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -38,9 +35,6 @@ DEFAULT_QT = 8
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:
     if interpret is None:
-        env = os.environ.get("MSCM_FORCE_INTERPRET", "")
-        if env != "":
-            return env.lower() not in ("0", "false", "no")
         return jax.default_backend() != "tpu"
     return bool(interpret)
 
@@ -184,6 +178,15 @@ def mscm_pallas(
     interp = _auto_interpret(interpret)
     if variant == "auto":
         variant = "fused" if x_dense.shape[1] <= VMEM_ROW_LIMIT else "pregather"
+    if variant == "fused" and not interp:
+        # Mosaic refuses the kernel's in-kernel 1-D gather over the VMEM
+        # query row ("Only 2D gather is supported"); never swap in another
+        # kernel behind the caller's back.
+        raise NotImplementedError(
+            "the fused MSCM kernel does not lower on TPU: its in-kernel "
+            "1-D jnp.take gather is refused by Mosaic; use "
+            "method='mscm_pallas_pregather' or 'mscm_pallas_grouped'"
+        )
     if sort:
         bq, bc, order = sort_blocks_by_chunk(block_q, block_c)
     else:

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.mscm import F32
+
 
 def mscm_ref(
     x_dense: jax.Array,   # f32 [n, d+1] (dense queries incl. sentinel slot)
@@ -34,7 +36,7 @@ def mscm_ref(
     row_ids = jnp.broadcast_to(rows[:, :, None], (c, r, b))
     w = w.at[row_ids.reshape(-1), col_ids.reshape(-1)].add(vals.reshape(-1))
     w = w.at[d_plus - 1, :].set(0.0)  # sentinel row carries no weight
-    full = x_dense @ w                                        # [n, C*B]
+    full = jnp.matmul(x_dense, w, precision=F32)              # [n, C*B]
     cols = block_c[:, None] * b + jnp.arange(b)[None, :]      # [A, B]
     return full[block_q[:, None], cols]
 

@@ -26,7 +26,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import mscm as mscm_lib
 from repro.core.beam import NEG_INF, beam_step
-from repro.core.distributed import shard_map_compat
 from repro.launch import hw
 from repro.launch.hlo_stats import collective_stats
 from repro.launch.mesh import make_production_mesh
@@ -78,7 +77,7 @@ def serve_step_spec(batch: int, beam: int, topk: int, mesh):
         upper, (leaf_rows, leaf_vals) = pairs[:-1], pairs[-1]
 
         @functools.partial(
-            shard_map_compat, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P("data", None), P("data", None),
                       tuple(P() for _ in range(2 * (n_levels - 1))),
                       P("model", None), P("model", None, None)),

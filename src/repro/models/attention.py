@@ -16,9 +16,10 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import get_abstract_mesh
 
 from repro.models.common import (ArchConfig, apply_rope, dense_init,
-                                 get_abstract_mesh, rms_norm, rope_angles)
+                                 rms_norm, rope_angles)
 
 
 # ---------------------------------------------------------------------------
@@ -62,7 +63,7 @@ def _attn_act_specs(cfg: ArchConfig, b, s, h, hkv):
     if cfg.attn_act_shard != "auto":
         return None, None, None
     am = get_abstract_mesh()
-    if am is None or am.empty or "model" not in am.axis_names:
+    if am.empty or "model" not in am.axis_names:
         return None, None, None
     from jax.sharding import PartitionSpec as _P
 

@@ -152,26 +152,6 @@ class ArchConfig:
 # primitives
 # ---------------------------------------------------------------------------
 
-def get_abstract_mesh():
-    """Version-portable ``jax.sharding.get_abstract_mesh``.
-
-    Public API from jax 0.5; on older versions fall back to the private
-    equivalent. Returns ``None`` when no usable abstract mesh is active
-    (including old versions where the fallback yields a bare tuple), so
-    callers can uniformly skip sharding constraints.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        try:
-            from jax._src.mesh import get_abstract_mesh as fn
-        except ImportError:
-            return None
-    am = fn()
-    if am is None or not hasattr(am, "axis_names") or getattr(am, "empty", False):
-        return None
-    return am
-
-
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     dt = x.dtype
     x = x.astype(jnp.float32)

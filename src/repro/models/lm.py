@@ -20,12 +20,13 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import get_abstract_mesh
 
 from repro.models import attention as attn_lib
 from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
 from repro.models.common import (ArchConfig, cross_entropy_loss, dense_init,
-                                 get_abstract_mesh, rms_norm)
+                                 rms_norm)
 
 Params = Dict[str, Any]
 
@@ -172,7 +173,7 @@ def _shard_act(x: jax.Array) -> jax.Array:
     production practice (cf. MaxText). No-op outside a mesh context or when
     the batch dim does not divide."""
     am = get_abstract_mesh()
-    if am is None or am.empty:
+    if am.empty:
         return x
     dp = tuple(a for a in ("pod", "data") if a in am.axis_names)
     if not dp:

@@ -19,10 +19,10 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import get_abstract_mesh
 
-from repro.models.common import ArchConfig, dense_init, get_abstract_mesh
+from repro.models.common import ArchConfig, dense_init
 
 
 def moe_init(key, cfg: ArchConfig) -> Dict[str, jax.Array]:
@@ -60,10 +60,10 @@ def _moe_spec(cfg: ArchConfig):
     from jax.sharding import PartitionSpec as _P
 
     am = get_abstract_mesh()
-    if am is not None and not am.empty and "model" in am.axis_names:
+    if not am.empty and "model" in am.axis_names:
         if cfg.n_experts % am.shape["model"] == 0:
             return _P("model", None, None)
-    if am is not None and not am.empty and "data" in am.axis_names:
+    if not am.empty and "data" in am.axis_names:
         return _P(None, "data", None)
     return _P(None, None, None)
 
@@ -131,7 +131,7 @@ def _moe_spec_grouped(cfg: ArchConfig):
     from jax.sharding import PartitionSpec as _P
 
     am = get_abstract_mesh()
-    if am is None or am.empty:
+    if am.empty:
         return _P(None, None, None, None)
     dp = tuple(a for a in ("pod", "data") if a in am.axis_names)
     b_ax = (dp if len(dp) > 1 else dp[0]) if dp else None

@@ -21,9 +21,8 @@ in-register dequant computes exactly ``q.astype(f32) * scale`` — the same
 elementwise reconstruction :func:`repro.quant.storage.dequantize_layer`
 materializes — so running this kernel on a :class:`QuantizedTree` is
 bitwise-identical (in interpret mode) to running the exact grouped kernel
-on the dequantized f32 tree. Interpret-mode fallback mirrors the exact
-kernels: ``MSCM_FORCE_INTERPRET`` / non-TPU backends run the kernel body in
-Python (``ops._auto_interpret``).
+on the dequantized f32 tree. Interpret mode follows the backend exactly as
+for the exact kernels (``ops._auto_interpret``).
 """
 
 from __future__ import annotations
@@ -36,6 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.mscm import F32
 from repro.kernels.ops import (
     DEFAULT_QT,
     _auto_interpret,
@@ -44,22 +44,26 @@ from repro.kernels.ops import (
 
 
 def _grouped_q_body(
-    tc_ref, xg_ref, ps_ref, vals_ref, scales_ref, out_ref, *, mode
+    tc_ref, xg_ref, ps_ref, vals_ref, scales_ref, out_ref, *, mode, rows
 ):
-    del tc_ref
+    # The scale block holds ``rows`` consecutive chunks' rows; pick this
+    # tile's chunk out of it.
+    row = tc_ref[pl.program_id(0)] % rows
+    scale = scales_ref[pl.ds(row, 1), :]                 # [1, B]
     # In-register dequant: widen the resident int8/fp8 chunk tile to f32 and
     # apply the per-column scale row while both live in VMEM — the f32 tile
     # never exists in HBM.
-    v = vals_ref[0].astype(jnp.float32) * scales_ref[0][None, :]  # [R, B]
+    v = vals_ref[0].astype(jnp.float32) * scale          # [R, B]
     acc = jax.lax.dot_general(
         xg_ref[0], v,
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=F32,
         preferred_element_type=jnp.float32,
     )                                                    # [QT, B]
     if mode == "prod":
-        acc = jax.nn.sigmoid(acc) * ps_ref[0][:, None]
+        acc = jax.nn.sigmoid(acc) * ps_ref[0]            # ps: [QT, 1]
     elif mode == "logsum":
-        acc = jax.nn.log_sigmoid(acc) + ps_ref[0][:, None]
+        acc = jax.nn.log_sigmoid(acc) + ps_ref[0]
     out_ref[0] = acc
 
 
@@ -91,23 +95,28 @@ def mscm_grouped_q(
                 "parent_scores (zeros would silently flatten every score)"
             )
         parent_scores = jnp.zeros((t, qt), jnp.float32)
+    # Mosaic wants every block's last two dims to be (8k, 128k) or the
+    # array's own: parent scores ride as [T, QT, 1], and the [C, B] scales
+    # are read 8 chunk rows at a time (the kernel picks its row) — a (1, B)
+    # block breaks the rule, and a [C, 1, B] copy would pad each row to 8.
+    rows = min(8, c)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(t,),
         in_specs=[
             pl.BlockSpec((1, qt, r), lambda i, tc: (i, 0, 0)),
-            pl.BlockSpec((1, qt), lambda i, tc: (i, 0)),
+            pl.BlockSpec((1, qt, 1), lambda i, tc: (i, 0, 0)),
             pl.BlockSpec((1, r, b), lambda i, tc: (tc[i], 0, 0)),
-            pl.BlockSpec((1, b), lambda i, tc: (tc[i], 0)),
+            pl.BlockSpec((rows, b), lambda i, tc: (tc[i] // rows, 0)),
         ],
         out_specs=pl.BlockSpec((1, qt, b), lambda i, tc: (i, 0, 0)),
     )
     return pl.pallas_call(
-        functools.partial(_grouped_q_body, mode=mode),
+        functools.partial(_grouped_q_body, mode=mode, rows=rows),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, qt, b), jnp.float32),
         interpret=interpret,
-    )(tile_chunk, xg_tiles, parent_scores, vals, scales)
+    )(tile_chunk, xg_tiles, parent_scores[..., None], vals, scales)
 
 
 def mscm_grouped_q_level(

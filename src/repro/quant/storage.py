@@ -41,10 +41,10 @@ import numpy as np
 from repro.core.tree import TreeLayerArrays, XMRTree
 
 #: Storage dtypes by name -> (numpy target dtype factory, symmetric qmax).
-#: fp8-e4m3 is present only when the backend's jax build ships the dtype.
-QUANT_DTYPES = {"int8": (np.int8, 127.0)}
-if hasattr(jnp, "float8_e4m3fn"):
-    QUANT_DTYPES["fp8"] = (np.dtype(jnp.float8_e4m3fn).type, 448.0)
+QUANT_DTYPES = {
+    "int8": (np.int8, 127.0),
+    "fp8": (np.dtype(jnp.float8_e4m3fn).type, 448.0),
+}
 
 
 @dataclasses.dataclass
@@ -97,11 +97,6 @@ def _dtype_for(tier: str) -> str:
     if tier in ("int8", "int8_pruned"):
         return "int8"
     if tier == "fp8":
-        if "fp8" not in QUANT_DTYPES:
-            raise ValueError(
-                "tier='fp8' needs jax.numpy.float8_e4m3fn, which this jax "
-                "build does not provide; use tier='int8'"
-            )
         return "fp8"
     raise ValueError(f"no storage dtype for tier {tier!r}")
 

@@ -169,15 +169,8 @@ class _InFlight:
 
 
 def _device_ready(inflight: _InFlight) -> bool:
-    """True when the in-flight batch's device results are ready.
-
-    Falls back to True (immediate, blocking finalize — the old behavior)
-    on jax versions whose arrays lack ``is_ready``.
-    """
-    try:
-        return bool(inflight.scores.is_ready() and inflight.labels.is_ready())
-    except AttributeError:
-        return True
+    """True when the in-flight batch's device results are ready."""
+    return bool(inflight.scores.is_ready() and inflight.labels.is_ready())
 
 
 # ``stream`` used to yield an ad-hoc (index, scores, labels, error) tuple

@@ -102,9 +102,7 @@ class FleetConfig:
             )
 
 
-#: Valid :attr:`QuantConfig.tier` values. ``"fp8"`` needs a jax build with
-#: ``float8_e4m3fn``; availability is checked when the tree is quantized
-#: (:func:`repro.quant.quantize_tree`), not here — config stays import-light.
+#: Valid :attr:`QuantConfig.tier` values.
 QUANT_TIERS = ("exact", "int8", "int8_pruned", "fp8")
 
 
@@ -124,8 +122,8 @@ class QuantConfig:
     * ``"int8_pruned"`` — int8 plus a magnitude-pruned ELL re-pack keeping
       the top ``prune_keep`` fraction of each chunk's rows (pad width R
       shrinks too).
-    * ``"fp8"`` — fp8-e4m3 storage where the backend has the dtype
-      (in-process serving only; the fleet wire is int8/f32).
+    * ``"fp8"`` — fp8-e4m3 storage (in-process serving only; the fleet
+      wire is int8/f32).
     """
 
     tier: str = "exact"

@@ -15,6 +15,7 @@ loss fails queries or serves survivor-exact partial rankings, and
 """
 
 from repro.serving.fleet.launcher import (
+    ChipHeldError,
     PartitionFleet,
     WorkerHandle,
     launch_workers,
@@ -36,6 +37,7 @@ from repro.serving.fleet.supervisor import (
 )
 
 __all__ = [
+    "ChipHeldError",
     "FaultInjector",
     "FaultRule",
     "FleetSupervisor",

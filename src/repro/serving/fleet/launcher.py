@@ -51,6 +51,16 @@ from repro.serving.fleet.rpc import WorkerConnection
 log = logging.getLogger(__name__)
 
 
+class ChipHeldError(RuntimeError):
+    """Fleet workers cannot start under a parent that holds a TPU.
+
+    A TPU chip belongs to one process at a time, and a parent that has
+    touched JAX holds it: every worker would fail on libtpu's lock, or sit
+    out its start-up timeout. Giving each worker its own chip is not
+    supported yet; on a chip host, serve the partitions in-process.
+    """
+
+
 class WorkerHandle:
     """One fleet worker: the process (when launched locally) + connection."""
 
@@ -122,12 +132,22 @@ def launch_workers(
 ) -> List[WorkerHandle]:
     """Spawn ``n`` local worker processes and connect to each.
 
-    The child environment inherits the parent's (so ``JAX_PLATFORMS``,
-    ``MSCM_FORCE_INTERPRET`` etc. propagate) with the directory containing
-    the ``repro`` package prepended to ``PYTHONPATH`` — workers import the
-    same code the parent runs, whatever the parent's install mode.
+    The child environment inherits the parent's (so ``JAX_PLATFORMS``
+    etc. propagate) with the directory containing the ``repro`` package
+    prepended to ``PYTHONPATH`` — workers import the same code the parent
+    runs, whatever the parent's install mode. Raises :class:`ChipHeldError`
+    at once when the parent's backend is a TPU (one process per chip).
     """
+    import jax
+
     import repro
+
+    if jax.default_backend() == "tpu":
+        raise ChipHeldError(
+            f"cannot launch {n} fleet workers: this process holds the TPU "
+            "and a chip serves one process at a time; serve the partitions "
+            "in-process (PartitionConfig) instead"
+        )
 
     pkg_root = os.path.dirname(os.path.dirname(os.path.abspath(repro.__file__)))
     child_env = dict(os.environ if env is None else env)

@@ -37,6 +37,23 @@ def rng():
     return np.random.default_rng(1234)
 
 
+#: Cross-method score tolerance. Within one method results are bitwise
+#: identical; across methods each sums the chunk dot products in its own
+#: order (XLA einsum, Pallas tile matmul, per-column dots), so scores may
+#: differ in their last bits. Four f32 ulp, relative to the largest score
+#: (a logit near 0 is a difference of large terms).
+CROSS_METHOD_RTOL = 4 * float(np.finfo(np.float32).eps)
+
+
+def assert_cross_method_close(got, want):
+    """Scores of two methods agree within :data:`CROSS_METHOD_RTOL`."""
+    got, want = np.asarray(got), np.asarray(want)
+    scale = float(np.abs(want).max(initial=0.0))
+    np.testing.assert_allclose(
+        got, want, rtol=CROSS_METHOD_RTOL, atol=CROSS_METHOD_RTOL * scale
+    )
+
+
 def make_tree_weights(rng, d, level_sizes, branching, nnz_per_col=10):
     """Random per-level CSC weight matrices with sibling-correlated support."""
     from repro.sparse import random_sparse_csc

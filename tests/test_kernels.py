@@ -23,6 +23,7 @@ from repro.kernels import ops
 from repro.kernels import ref as ref_lib
 from repro.kernels.mscm_kernel import group_blocks_by_chunk
 from repro.sparse import random_sparse_csc, random_sparse_csr
+from tests.conftest import assert_cross_method_close
 
 
 def _mk(rng, n, d, C, B, nnz_w, nnz_x, A):
@@ -76,12 +77,12 @@ def test_grouped_kernel(rng, qt):
 
 
 def test_grouped_bitwise_vs_dense_lookup(rng):
-    """The grouped kernel's per-block result is *bitwise* the dense-lookup
-    einsum — row independence of the tile matmul, pinned at kernel level."""
+    """The grouped kernel's per-block result is the dense-lookup einsum's to
+    a few ulp: the two sum the R terms in different orders."""
     xd, rows, vals, bq, bc, _ = _mk(rng, n=6, d=90, C=4, B=8, nnz_w=8, nnz_x=10, A=13)
     dense = M.mscm_dense_lookup(xd, rows, vals, jnp.asarray(bq), jnp.asarray(bc))
     got = ops.mscm_pallas_grouped(xd, rows, vals, bq, bc, qt=4, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(dense))
+    assert_cross_method_close(got, dense)
 
 
 @pytest.mark.parametrize("mode", ["prod", "logsum"])
@@ -97,7 +98,14 @@ def test_grouped_fused_epilogue(rng, mode):
     got = ops.mscm_pallas_grouped(
         xd, rows, vals, bq, bc, ps, qt=4, mode=mode, interpret=True
     )
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert_cross_method_close(got, want)
+    # bitwise against the same kernel's raw logits, epilogue applied outside
+    raw_k = ops.mscm_pallas_grouped(xd, rows, vals, bq, bc, qt=4, interpret=True)
+    if mode == "prod":
+        want_k = jax.nn.sigmoid(raw_k) * ps[:, None]
+    else:
+        want_k = jax.nn.log_sigmoid(raw_k) + ps[:, None]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want_k))
 
 
 def test_group_blocks_by_chunk():
@@ -150,18 +158,25 @@ def test_unsort_is_gather_inverse(rng):
 
 
 def test_force_interpret_env(monkeypatch):
-    """MSCM_FORCE_INTERPRET pins interpret mode regardless of backend."""
-    monkeypatch.setenv("MSCM_FORCE_INTERPRET", "1")
-    assert ops._auto_interpret(None) is True
-    monkeypatch.setenv("MSCM_FORCE_INTERPRET", "0")
-    assert ops._auto_interpret(None) is False
-    monkeypatch.setenv("MSCM_FORCE_INTERPRET", "false")
-    assert ops._auto_interpret(None) is False
-    monkeypatch.delenv("MSCM_FORCE_INTERPRET")
+    """Interpret mode follows the backend and nothing else: compiled on a
+    TPU, interpreted elsewhere; an explicit argument always wins."""
     assert ops._auto_interpret(None) == (jax.default_backend() != "tpu")
-    # explicit argument always wins
-    monkeypatch.setenv("MSCM_FORCE_INTERPRET", "0")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._auto_interpret(None) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._auto_interpret(None) is True
     assert ops._auto_interpret(True) is True
+    assert ops._auto_interpret(False) is False
+
+
+def test_fused_refused_when_compiled(rng):
+    """The fused kernel's in-kernel 1-D gather does not lower on Mosaic, so a
+    compiled (non-interpret) fused call raises rather than silently running
+    another kernel."""
+    xd, rows, vals, bq, bc, _ = _mk(rng, n=4, d=64, C=3, B=8, nnz_w=6, nnz_x=8, A=8)
+    with pytest.raises(NotImplementedError, match="gather"):
+        ops.mscm_pallas(xd, rows, vals, jnp.asarray(bq), jnp.asarray(bc),
+                        variant="fused", interpret=False)
 
 
 if HAS_HYPOTHESIS:

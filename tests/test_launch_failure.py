@@ -126,3 +126,20 @@ def test_launch_cleanup_failure_is_logged_not_masking(
         "launch cleanup" in rec.message and "kill" in rec.message
         for rec in caplog.records
     )
+
+
+def test_launch_under_tpu_parent_raises_at_once(monkeypatch):
+    """A parent that holds a TPU cannot hand the chip to workers: launch
+    refuses before spawning anything, instead of waiting out the
+    announcement timeout."""
+    import jax
+
+    from repro.serving.fleet import ChipHeldError
+
+    def _no_spawn(*args, **kwargs):
+        raise AssertionError("launch_workers spawned a worker")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(launcher_mod.subprocess, "Popen", _no_spawn)
+    with pytest.raises(ChipHeldError, match="one process"):
+        launch_workers(2, startup_timeout_s=120.0)

@@ -26,7 +26,6 @@ from repro.core import XMRTree
 from repro.index import ScatterGatherPlanner, partition_tree
 from repro.index.partition import MANIFEST_VERSION, PartitionManifest
 from repro.quant import (
-    QUANT_DTYPES,
     QuantizedTree,
     dequantize_layer,
     dequantize_tree,
@@ -377,8 +376,6 @@ def test_fleet_int8_bitwise_vs_in_process(quant_setup):
     _assert_bitwise(got, ref)
 
 
-@pytest.mark.skipif("fp8" not in QUANT_DTYPES,
-                    reason="jax build lacks float8_e4m3fn")
 def test_fleet_rejects_fp8_wire(quant_setup):
     """fp8 serves in-process only: numpy dtype strings cannot carry
     ml_dtypes over the RPC wire, so shipping it must fail loudly."""
@@ -392,8 +389,6 @@ def test_fleet_rejects_fp8_wire(quant_setup):
                           score_mode="prod", qt=8)
 
 
-@pytest.mark.skipif("fp8" not in QUANT_DTYPES,
-                    reason="jax build lacks float8_e4m3fn")
 def test_fp8_tier_in_process(quant_setup):
     tree, _, xi, xv = quant_setup
     qtree = quantize_tree(tree, tier="fp8")
