@@ -244,4 +244,5 @@ def mscm_grouped(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, qt, b), jnp.float32),
         interpret=interpret,
+        name="mscm_grouped",
     )(tile_chunk, xg_tiles, parent_scores[..., None], vals)

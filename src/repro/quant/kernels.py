@@ -116,6 +116,7 @@ def mscm_grouped_q(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, qt, b), jnp.float32),
         interpret=interpret,
+        name="mscm_grouped_q",
     )(tile_chunk, xg_tiles, parent_scores[..., None], vals, scales)
 
 

@@ -40,6 +40,7 @@ from typing import Iterable, Iterator, List, Optional, Tuple, Union
 import jax
 import numpy as np
 
+from repro.serving import spans
 from repro.serving.admission import AdmissionController, AdmissionPolicy
 from repro.serving.api import Query, QueryResult
 from repro.serving.engine import XMRServingEngine
@@ -166,6 +167,8 @@ class _InFlight:
     degraded: Optional[dict] = None
     # Beam tier this batch was dispatched at (0 = full beam).
     tier: int = 0
+    # Engine-wide dispatch id, which tags the batch's spans.
+    dispatch: int = -1
 
 
 def _device_ready(inflight: _InFlight) -> bool:
@@ -456,25 +459,33 @@ class MicroBatcher:
             queue_depth=len(self.queue), budget_ms=budget
         )
 
-    def _dispatch(self, reqs: List[_Request], trigger: str) -> _InFlight:
-        t_dequeue = time.perf_counter()
-        tier = self._select_tier(reqs, t_dequeue)
-        d = self.engine.tree.d
-        sub = CSR.from_rows(
-            [r.idx for r in reqs], [r.val for r in reqs], (len(reqs), d)
-        )
-        bucket = self.engine.bucket_for(len(reqs))
-        xi, xv = self.engine.marshal_rows(sub, np.arange(len(reqs)), bucket)
-        # async dispatch — do not block here
-        s, l = self.engine._run(xi, xv, tier=tier)
-        return _InFlight(
-            reqs, s, l, t_dequeue, bucket, trigger,
-            degraded=self.engine.last_degraded(),
-            tier=tier,
-        )
+    def _dispatch(
+        self, reqs: List[_Request], trigger: str, dispatch: int
+    ) -> _InFlight:
+        with spans.span(spans.BATCH_DISPATCH, dispatch, requests=len(reqs)):
+            t_dequeue = time.perf_counter()
+            tier = self._select_tier(reqs, t_dequeue)
+            d = self.engine.tree.d
+            sub = CSR.from_rows(
+                [r.idx for r in reqs], [r.val for r in reqs], (len(reqs), d)
+            )
+            bucket = self.engine.bucket_for(len(reqs))
+            xi, xv = self.engine.marshal_rows(
+                sub, np.arange(len(reqs)), bucket, dispatch=dispatch
+            )
+            # async dispatch — do not block here
+            with spans.span(spans.DISPATCH, dispatch, bucket=bucket,
+                            tier=tier):
+                s, l = self.engine._run(xi, xv, tier=tier)
+            return _InFlight(
+                reqs, s, l, t_dequeue, bucket, trigger,
+                degraded=self.engine.last_degraded(),
+                tier=tier,
+                dispatch=dispatch,
+            )
 
     def _try_dispatch(
-        self, reqs: List[_Request], trigger: str
+        self, reqs: List[_Request], trigger: str, dispatch: int
     ) -> Optional[_InFlight]:
         """Expire dead requests, dispatch the survivors, fail on error.
 
@@ -486,7 +497,7 @@ class MicroBatcher:
         if not live:
             return None
         try:
-            return self._dispatch(live, trigger)
+            return self._dispatch(live, trigger, dispatch)
         except BaseException as exc:  # noqa: BLE001 — fail the batch, keep serving
             self._fail(live, exc)
             return None
@@ -496,38 +507,45 @@ class MicroBatcher:
         # stall: host dispatch already returned, so everything the worker
         # waits on here is device time the scatter-gather exchange failed
         # to overlap (the figure sync="pipelined" exists to shrink).
+        dispatch = inflight.dispatch
         t_wait = time.perf_counter()
-        jax.block_until_ready((inflight.scores, inflight.labels))
+        with spans.span(spans.WAIT, dispatch):
+            jax.block_until_ready((inflight.scores, inflight.labels))
         t_done = time.perf_counter()
         partitioned = self.engine.planner is not None
-        s = np.asarray(inflight.scores)
-        leaves = np.asarray(inflight.labels)
-        l = self.engine._map_labels(leaves)
-        for i, req in enumerate(inflight.reqs):
+        with spans.span(spans.FETCH, dispatch):
+            s = np.asarray(inflight.scores)
+            leaves = np.asarray(inflight.labels)
+            l = self.engine._map_labels(leaves)
+        with spans.span(spans.RESOLVE, dispatch,
+                        requests=len(inflight.reqs)):
+            for i, req in enumerate(inflight.reqs):
+                if inflight.degraded is not None:
+                    # Attribute channel to the v1 wrapper: set before
+                    # set_result because done-callbacks fire synchronously.
+                    req.future.degraded_info = inflight.degraded
+                if inflight.tier:
+                    req.future.beam_tier = inflight.tier
+                req.future.set_result((s[i], l[i]))
             if inflight.degraded is not None:
-                # Attribute channel to the v1 wrapper: set before
-                # set_result because done-callbacks fire synchronously.
-                req.future.degraded_info = inflight.degraded
-            if inflight.tier:
-                req.future.beam_tier = inflight.tier
-            req.future.set_result((s[i], l[i]))
-        if inflight.degraded is not None:
-            self.metrics.record_degraded(len(inflight.reqs))
-        # Partition occupancy uses raw leaves (pre-label_perm) and only the
-        # real rows — bucket padding tails are sentinel junk.
-        hits = self.engine.partition_hit_counts(leaves[: len(inflight.reqs)])
-        self.metrics.record_batch(
-            t_enqueue=[r.t_enqueue for r in inflight.reqs],
-            t_dequeue=inflight.t_dequeue,
-            t_done=t_done,
-            bucket=inflight.bucket,
-            trigger=inflight.trigger,
-            shards=self.engine.config.shards,
-            partition_hits=hits,
-            stall_ms=1e3 * (t_done - t_wait) if partitioned else None,
-            cache_stats=self.engine.beam_cache_stats(),
-            tier=inflight.tier,
-        )
+                self.metrics.record_degraded(len(inflight.reqs))
+            # Partition occupancy uses raw leaves (pre-label_perm) and only
+            # the real rows — bucket padding tails are sentinel junk.
+            hits = self.engine.partition_hit_counts(
+                leaves[: len(inflight.reqs)]
+            )
+            self.metrics.record_batch(
+                t_enqueue=[r.t_enqueue for r in inflight.reqs],
+                t_dequeue=inflight.t_dequeue,
+                t_done=t_done,
+                bucket=inflight.bucket,
+                trigger=inflight.trigger,
+                shards=self.engine.config.shards,
+                partition_hits=hits,
+                stall_ms=1e3 * (t_done - t_wait) if partitioned else None,
+                cache_stats=self.engine.beam_cache_stats(),
+                tier=inflight.tier,
+            )
 
     def _fail(self, reqs: List[_Request], exc: BaseException) -> None:
         for r in reqs:
@@ -560,24 +578,26 @@ class MicroBatcher:
         p = self.policy
         wait_s = 1e-3 * p.max_wait_ms
         pending: _InFlight | None = None
+        # Id of the batch being formed; a new one once a batch has formed.
+        dispatch = self.engine.next_dispatch_id()
         while True:
-            if pending is None:
-                reqs, trigger = self.queue.next_batch(p.max_batch, wait_s)
-                if reqs is None:
-                    break
-                pending = self._try_dispatch(reqs, trigger)
-            else:
-                reqs, trigger = self._poll_ready(pending, wait_s)
-                # Double-buffer: the ready batch goes on the device first;
-                # only then block on the previous batch's results.
-                nxt = self._try_dispatch(reqs, trigger) if reqs else None
+            with spans.span(spans.FORM, dispatch) as span:
+                if pending is None:
+                    reqs, trigger = self.queue.next_batch(p.max_batch, wait_s)
+                else:
+                    reqs, trigger = self._poll_ready(pending, wait_s)
+                span.set_metadata(trigger=trigger, requests=len(reqs or ()))
+            if reqs is None and pending is None:
+                break
+            # Double-buffer: the ready batch goes on the device first;
+            # only then block on the previous batch's results.
+            nxt = None
+            if reqs:
+                nxt = self._try_dispatch(reqs, trigger, dispatch)
+                dispatch = self.engine.next_dispatch_id()
+            if pending is not None:
                 try:
                     self._finalize(pending)
                 except BaseException as exc:  # noqa: BLE001
                     self._fail(pending.reqs, exc)
-                pending = nxt
-        if pending is not None:
-            try:
-                self._finalize(pending)
-            except BaseException as exc:  # noqa: BLE001
-                self._fail(pending.reqs, exc)
+            pending = nxt

@@ -39,6 +39,7 @@ replicas behind one batcher.
 
 from __future__ import annotations
 
+import itertools
 import time
 from typing import Optional, Sequence, Tuple
 
@@ -47,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.tree import XMRTree
+from repro.serving import spans
 from repro.serving.config import (
     AdmissionConfig,
     PartitionConfig,
@@ -134,6 +136,7 @@ class XMRServingEngine:
                     )
         self.label_perm = label_perm  # leaf position -> original label id
         self.stats = LatencyStats()
+        self._dispatch_ids = itertools.count()
         self.mesh = None
         self._batch_sharding = None
         self.index = None
@@ -204,21 +207,29 @@ class XMRServingEngine:
         self.tree = tree
 
     # -- query marshalling --------------------------------------------------
-    def marshal_rows(self, queries: CSR, rows: np.ndarray, bucket: int
-                     ) -> Tuple[jax.Array, jax.Array]:
+    def next_dispatch_id(self) -> int:
+        """The next engine-wide dispatch sequence number, which tags the
+        dispatch's host spans (:mod:`repro.serving.spans`)."""
+        return next(self._dispatch_ids)
+
+    def marshal_rows(self, queries: CSR, rows: np.ndarray, bucket: int,
+                     *, dispatch: int = -1) -> Tuple[jax.Array, jax.Array]:
         """Vectorized ELL marshalling padded up to a jit bucket.
 
         Padding rows use the sentinel index ``d`` and value 0, i.e. empty
-        queries — the bucket tail is sliced off by the caller.
+        queries — the bucket tail is sliced off by the caller. ``dispatch``
+        tags the marshal span (-1 outside a dispatch).
         """
-        w = self.config.ell_width
-        d = queries.shape[1]
-        idx, val = rows_to_ell(queries, rows, w)
-        if bucket > len(rows):
-            pad = bucket - len(rows)
-            idx = np.concatenate([idx, np.full((pad, w), d, np.int32)])
-            val = np.concatenate([val, np.zeros((pad, w), np.float32)])
-        return jnp.asarray(idx), jnp.asarray(val)
+        with spans.span(spans.MARSHAL, dispatch, rows=len(rows),
+                        bucket=bucket):
+            w = self.config.ell_width
+            d = queries.shape[1]
+            idx, val = rows_to_ell(queries, rows, w)
+            if bucket > len(rows):
+                pad = bucket - len(rows)
+                idx = np.concatenate([idx, np.full((pad, w), d, np.int32)])
+                val = np.concatenate([val, np.zeros((pad, w), np.float32)])
+            return jnp.asarray(idx), jnp.asarray(val)
 
     def bucket_for(self, n: int) -> int:
         """Power-of-two jit bucket for ``n`` queries.
@@ -300,29 +311,32 @@ class XMRServingEngine:
         out_s, out_l = [], []
 
         def finalize(pending) -> None:
-            s, l, count = pending
-            jax.block_until_ready((s, l))
-            out_s.append(np.asarray(s)[:count])
-            out_l.append(np.asarray(l)[:count])
+            s, l, count, dispatch = pending
+            with spans.span(spans.WAIT, dispatch):
+                jax.block_until_ready((s, l))
+            with spans.span(spans.FETCH, dispatch):
+                out_s.append(np.asarray(s)[:count])
+                out_l.append(self._map_labels(np.asarray(l)[:count]))
 
         t_start = time.perf_counter()
         pending = None
         i = 0
         while i < n:
+            dispatch = self.next_dispatch_id()
             count = min(self.config.max_batch, n - i)
             bucket = self.bucket_for(count)
-            xi, xv = self.marshal_rows(queries, np.arange(i, i + count), bucket)
-            s, l = self._run(xi, xv)  # async dispatch
+            xi, xv = self.marshal_rows(queries, np.arange(i, i + count), bucket,
+                                       dispatch=dispatch)
+            with spans.span(spans.DISPATCH, dispatch, bucket=bucket, tier=0):
+                s, l = self._run(xi, xv)  # async dispatch
             if pending is not None:
                 finalize(pending)
-            pending = (s, l, count)
+            pending = (s, l, count, dispatch)
             i += count
         if pending is not None:
             finalize(pending)
         self.stats.record_amortized(time.perf_counter() - t_start, n)
-        scores = np.concatenate(out_s)
-        leaves = np.concatenate(out_l)
-        return scores, self._map_labels(leaves)
+        return np.concatenate(out_s), np.concatenate(out_l)
 
     def serve_online(self, queries: CSR, limit: int | None = None
                      ) -> Tuple[np.ndarray, np.ndarray]:
@@ -331,16 +345,19 @@ class XMRServingEngine:
         out_s, out_l = [], []
         bucket = self.bucket_for(1)  # 1 unsharded; >= shards on a mesh
         for i in range(n):
-            xi, xv = self.marshal_rows(queries, np.arange(i, i + 1), bucket)
+            dispatch = self.next_dispatch_id()
+            xi, xv = self.marshal_rows(queries, np.arange(i, i + 1), bucket,
+                                       dispatch=dispatch)
             t0 = time.perf_counter()
-            s, l = self._run(xi, xv)
-            jax.block_until_ready((s, l))
+            with spans.span(spans.DISPATCH, dispatch, bucket=bucket, tier=0):
+                s, l = self._run(xi, xv)
+            with spans.span(spans.WAIT, dispatch):
+                jax.block_until_ready((s, l))
             self.stats.record(time.perf_counter() - t0)
-            out_s.append(np.asarray(s)[0])
-            out_l.append(np.asarray(l)[0])
-        scores = np.stack(out_s)
-        leaves = np.stack(out_l)
-        return scores, self._map_labels(leaves)
+            with spans.span(spans.FETCH, dispatch):
+                out_s.append(np.asarray(s)[0])
+                out_l.append(self._map_labels(np.asarray(l)[0]))
+        return np.stack(out_s), np.stack(out_l)
 
     def _map_labels(self, leaves: np.ndarray) -> np.ndarray:
         if self.label_perm is None:

@@ -17,12 +17,17 @@ Pins the properties the async engine must not break:
 7. the ``queue_depth="auto"`` capacity probe is total — zero/slow drain
    rates and a missing deadline all resolve to a sane bound — and
    ``stop()`` during an in-flight probe waits it out instead of closing
-   the queue under it.
+   the queue under it;
+8. every dispatch's host spans (``repro.*``, on the profiler's clock) share
+   its dispatch id and nest in the order the serving path runs them, and a
+   profiler session leaves results bitwise unchanged.
 """
 
+import glob
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -35,6 +40,7 @@ from repro.serving import (
     ServeConfig,
     XMRServingEngine,
 )
+from repro.serving import spans
 from repro.serving.batcher import (
     TRIGGER_DEADLINE,
     TRIGGER_FLUSH,
@@ -507,3 +513,91 @@ def test_stop_during_auto_probe_waits_probe_out(serving_setup, monkeypatch):
     # start completed its probe (bound resolved), stop joined the worker
     assert isinstance(mb.admission.max_queue_depth, int)
     assert mb.queue.closed and mb._thread is None
+
+
+# ---------------------------------------------------------------------------
+# 8. host spans of each dispatch
+# ---------------------------------------------------------------------------
+
+def _traced(tmp_path, fn):
+    """``fn()`` under a profiler session; its result and the ``repro.*``
+    host spans as ``(name, start_ns, end_ns, line, stats)``."""
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        out = fn()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = []
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            found.extend(
+                (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, i,
+                 dict(ev.stats))
+                for ev in line.events if ev.name.startswith("repro.")
+            )
+    return out, found
+
+
+def test_serve_batch_bitwise_with_profiler_on_and_off(serving_setup, tmp_path):
+    engine, queries, ref_s, ref_l = serving_setup
+    off_s, off_l = engine.serve_batch(queries)
+    (on_s, on_l), found = _traced(tmp_path, lambda: engine.serve_batch(queries))
+    assert found  # the session recorded the spans
+    np.testing.assert_array_equal(on_s, off_s)
+    np.testing.assert_array_equal(on_l, off_l)
+    np.testing.assert_array_equal(on_s, ref_s)
+
+
+def _microbatched(engine, queries):
+    mb = MicroBatcher(engine, BatchPolicy(max_batch=16, max_wait_ms=5.0),
+                      warmup_on_start=False)
+    futs = mb.submit_csr(queries)  # 45 → batches of 16, 16 and 13
+    mb.start()
+    out = [f.result(timeout=60) for f in futs]
+    mb.stop()  # the worker's last spans end inside the session
+    return out
+
+
+@pytest.mark.parametrize("entry", ["serve_batch", "serve_online",
+                                   "microbatcher"])
+def test_spans_join_each_dispatch(serving_setup, tmp_path, entry):
+    """Every dispatch has its marshal, enqueue, wait and fetch spans under
+    one id on one thread, in that order; a micro-batch's marshal and
+    enqueue nest in its batcher dispatch, which follows its forming and
+    precedes its resolution."""
+    engine, queries, *_ = serving_setup
+    run = {"serve_batch": lambda: engine.serve_batch(queries),
+           "serve_online": lambda: engine.serve_online(queries, limit=5),
+           "microbatcher": lambda: _microbatched(engine, queries)}[entry]
+    _, found = _traced(tmp_path, run)
+    by_id = {}
+    for name, start, end, line, stats in found:
+        by_id.setdefault(stats["dispatch"], {}).setdefault(name, []).append(
+            (start, end, line, stats))
+    enqueued = [d for d, named in by_id.items() if spans.DISPATCH in named]
+    assert len(enqueued) == {"serve_batch": 1, "serve_online": 5,
+                             "microbatcher": 3}[entry]
+    batched = entry == "microbatcher"
+    for d in enqueued:
+        named = by_id[d]
+        want = {spans.MARSHAL, spans.DISPATCH, spans.WAIT, spans.FETCH}
+        if batched:
+            want |= {spans.FORM, spans.BATCH_DISPATCH, spans.RESOLVE}
+        assert set(named) == want
+        assert len({line for evs in named.values() for _, _, line, _ in evs}) == 1
+        assert all(len(evs) == 1 for n, evs in named.items() if n != spans.FORM)
+        one = {n: evs[-1] for n, evs in named.items()}
+        order = [spans.MARSHAL, spans.DISPATCH, spans.WAIT, spans.FETCH]
+        if batched:
+            order.append(spans.RESOLVE)
+        for a, b in zip(order, order[1:]):
+            assert one[a][1] <= one[b][0], (a, b)
+        assert one[spans.MARSHAL][3]["bucket"] >= one[spans.MARSHAL][3]["rows"]
+        assert one[spans.DISPATCH][3]["tier"] == 0
+        if batched:
+            outer = one[spans.BATCH_DISPATCH]
+            assert one[spans.FORM][1] <= outer[0]
+            for inner in (spans.MARSHAL, spans.DISPATCH):
+                assert outer[0] <= one[inner][0] <= one[inner][1] <= outer[1]
+            assert one[spans.FORM][3]["requests"] == outer[3]["requests"]
+            assert one[spans.RESOLVE][3]["requests"] == outer[3]["requests"]

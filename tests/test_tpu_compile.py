@@ -12,6 +12,7 @@ process may load the TPU library, and the tests run under several workers.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,8 +55,16 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(compiled, name=None):
+    """A Mosaic kernel is in the program; its HLO instruction carries
+    ``name`` (the ``pallas_call``'s) where one is given."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if name is not None:
+        assert re.search(
+            rf"%{name}(\.\d+)? = .*custom_call_target=\"tpu_custom_call\"",
+            text,
+        )
 
 
 def test_grouped_compiles_for_v5e(one_chip):
@@ -70,7 +79,7 @@ def test_grouped_compiles_for_v5e(one_chip):
         s((C, R, B), jnp.float32), s((A,), jnp.int32), s((A,), jnp.int32),
         s((A,), jnp.float32),
     ).compile()
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "mscm_grouped")
 
 
 def test_grouped_int8_compiles_for_v5e(one_chip):
@@ -86,7 +95,7 @@ def test_grouped_int8_compiles_for_v5e(one_chip):
         s((C, R, B), jnp.int8), s((C, B), jnp.float32), s((A,), jnp.int32),
         s((A,), jnp.int32), s((A,), jnp.float32),
     ).compile()
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "mscm_grouped_q")
 
 
 def test_pregather_compiles_for_v5e(one_chip):
