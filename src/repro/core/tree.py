@@ -65,7 +65,8 @@ from repro.sparse.csr import CSC
 #                         fused in-kernel. The high-throughput batch TPU
 #                         path — amortizes each chunk tile over up to QT
 #                         queries and keeps the whole traversal in one XLA
-#                         program.
+#                         program. Query tiles come from intersecting the
+#                         ELL queries with the chunk rows: no dense table.
 #   mscm_pallas_grouped_q the grouped kernel over *quantized* chunk tiles
 #                         (int8/fp8 + per-column scales, repro.quant):
 #                         dequantize-in-register before the tile matmul.
@@ -377,7 +378,9 @@ def level_combined(
         # epilogue all happen inside the kernel dispatch — the combined beam
         # scores are the only HBM round-trip per level.
         return ops.mscm_grouped_level(
-            x_dense,
+            x_idx,
+            x_val,
+            d,
             layer.chunk_rows,
             layer.chunk_vals,
             block_q,
@@ -393,7 +396,9 @@ def level_combined(
         # with the int8/fp8 chunk tile dequantized in-register against its
         # per-column scale row (layer is a QuantLayerArrays).
         return qkernels.mscm_grouped_q_level(
-            x_dense,
+            x_idx,
+            x_val,
+            d,
             layer.chunk_rows,
             layer.chunk_vals,
             layer.chunk_scales,
@@ -480,7 +485,6 @@ def _tree_infer(
     n = x_idx.shape[0]
     needs_dense = method in (
         "mscm_dense", "mscm_pallas", "mscm_pallas_pregather",
-        "mscm_pallas_grouped", "mscm_pallas_grouped_q",
     )
     x_dense = mscm_lib.scatter_dense(x_idx, x_val, d) if needs_dense else None
 

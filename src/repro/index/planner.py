@@ -420,7 +420,6 @@ class ScatterGatherPlanner:
             ]
         self._needs_dense = method in (
             "mscm_dense", "mscm_pallas", "mscm_pallas_pregather",
-            "mscm_pallas_grouped", "mscm_pallas_grouped_q",
         )
         # The router head is always exact f32 (only the partitions are
         # quantized — repro.quant.quantize_index), so a quantized method

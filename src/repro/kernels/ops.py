@@ -5,9 +5,11 @@ Mosaic; on any other backend (the CPU test runs) the kernel body executes in
 the Pallas interpreter, for correctness only. Nothing else switches it.
 
 The grouped path is fully device-resident: :func:`group_blocks_device`
-derives the chunk-major query tiles *inside* the jit (no host round-trip),
-so the entire multi-level beam search — scatter, group, matmul tiles,
-epilogue, top-k — compiles as one XLA program (paper §4, Alg. 3).
+derives the chunk-major tiling *inside* the jit (no host round-trip) and
+:func:`intersect_query_tiles` fills the query tiles from the ELL queries,
+so the entire multi-level beam search — group, intersect, matmul tiles,
+epilogue, top-k — compiles as one XLA program (paper §4, Alg. 3) with no
+dense query table.
 """
 
 from __future__ import annotations
@@ -113,8 +115,42 @@ def group_blocks_device(
     return tile_chunk, tile_src, order, flat_pos
 
 
+def intersect_query_tiles(
+    x_idx: jax.Array,          # int32 [n, Q] ELL ids, sentinel-padded (== d)
+    x_val: jax.Array,          # f32 [n, Q]
+    d: int,
+    rows: jax.Array,           # int32 [C, R] chunk rows, sentinel-padded
+    block_q: jax.Array,        # int32 [A]
+    block_c: jax.Array,        # int32 [A]
+    tile_src: jax.Array,       # int32 [T, QT] block per tile slot, -1 = pad
+) -> jax.Array:
+    """The grouped kernels' [T, QT, R] query tiles, by intersection.
+
+    Each block's row holds its query's value at each of its chunk's rows:
+    the query's ELL ids are compared with the chunk's row list and the
+    matching values summed (the paper's intersection iterator, §4, as
+    compare, select and add on the vector unit). A query's ids are
+    distinct, so each element has at most one nonzero term and equals what
+    a dense ``scatter_dense`` table would hold, bit for bit; a duplicated
+    id sums, as the table's ``.add`` does. The intersection runs once per
+    block ([A, Q] x [A, R] -> [A, R]); whole rows are then gathered into
+    the tile layout through ``tile_src``, and padding slots are exact zeros.
+    No [n, d+1] table is built.
+    """
+    xi = x_idx[block_q]                                  # [A, Q]
+    xv = x_val[block_q]                                  # [A, Q]
+    r = rows[block_c]                                    # [A, R]
+    hit = xi[:, :, None] == r[:, None, :]                # [A, Q, R], fused
+    xa = jnp.sum(jnp.where(hit, xv[:, :, None], 0), axis=1)   # [A, R]
+    xa = jnp.where(r < d, xa, 0)                         # sentinel rows
+    xg = xa[jnp.maximum(tile_src, 0)]                    # [T, QT, R]
+    return jnp.where((tile_src >= 0)[..., None], xg, 0)
+
+
 def mscm_grouped_level(
-    x_dense: jax.Array,        # f32 [n, Dp]
+    x_idx: jax.Array,          # int32 [n, Q]
+    x_val: jax.Array,          # f32 [n, Q]
+    d: int,
     rows: jax.Array,           # int32 [C, R]
     vals: jax.Array,           # f32 [C, R, B]
     block_q: jax.Array,        # int32 [A]
@@ -127,24 +163,25 @@ def mscm_grouped_level(
 ) -> jax.Array:
     """One tree level through the MXU-tiled grouped kernel, fully in-jit.
 
-    Groups the active blocks chunk-major on device, gathers the query rows
-    into [T, QT, R] tiles, runs one [QT, R] x [R, B] matmul per tile with the
-    beam epilogue fused (``mode`` — see :func:`mscm_grouped`), and returns
-    the [A, B] block scores in the original block order via a pure-gather
-    unsort. Traceable: safe to call inside an enclosing jit.
+    Groups the active blocks chunk-major on device, builds the [T, QT, R]
+    query tiles by intersecting the ELL queries with the chunk rows
+    (:func:`intersect_query_tiles`), runs one [QT, R] x [R, B] matmul per
+    tile with the beam epilogue fused (``mode`` — see
+    :func:`mscm_grouped`), and returns the [A, B] block scores in the
+    original block order via a pure-gather unsort. Traceable: safe to call
+    inside an enclosing jit.
     """
     interp = _auto_interpret(interpret)
     c, _, b = vals.shape
     tile_chunk, tile_src, order, flat_pos = group_blocks_device(
         block_c, qt, c
     )
-    safe_src = jnp.maximum(tile_src, 0)                  # [T, QT]
-    bq = block_q[safe_src]                               # [T, QT]
-    r = rows[tile_chunk]                                 # [T, R]
-    xg = x_dense[bq[..., None], r[:, None, :]]           # [T, QT, R]
-    xg = jnp.where((tile_src >= 0)[..., None], xg, 0.0)
+    xg = intersect_query_tiles(
+        x_idx, x_val, d, rows, block_q, block_c, tile_src
+    )
     ps = None
     if parent_scores is not None:
+        safe_src = jnp.maximum(tile_src, 0)              # [T, QT]
         ps = jnp.where(tile_src >= 0, parent_scores[safe_src], 0.0)
     tiles = mscm_grouped(
         xg, vals, tile_chunk, ps, mode=mode, interpret=interp
@@ -202,10 +239,12 @@ def mscm_pallas(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("qt", "mode", "interpret")
+    jax.jit, static_argnames=("d", "qt", "mode", "interpret")
 )
 def mscm_pallas_grouped(
-    x_dense: jax.Array,
+    x_idx: jax.Array,
+    x_val: jax.Array,
+    d: int,
     rows: jax.Array,
     vals: jax.Array,
     block_q: jax.Array,
@@ -224,6 +263,6 @@ def mscm_pallas_grouped(
     f32 [A, B] in the original block order.
     """
     return mscm_grouped_level(
-        x_dense, rows, vals, block_q, block_c, parent_scores,
+        x_idx, x_val, d, rows, vals, block_q, block_c, parent_scores,
         qt=qt, mode=mode, interpret=interpret,
     )

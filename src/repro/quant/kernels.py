@@ -40,6 +40,7 @@ from repro.kernels.ops import (
     DEFAULT_QT,
     _auto_interpret,
     group_blocks_device,
+    intersect_query_tiles,
 )
 
 
@@ -121,7 +122,9 @@ def mscm_grouped_q(
 
 
 def mscm_grouped_q_level(
-    x_dense: jax.Array,        # f32 [n, Dp]
+    x_idx: jax.Array,          # int32 [n, Q]
+    x_val: jax.Array,          # f32 [n, Q]
+    d: int,
     rows: jax.Array,           # int32 [C, R]
     vals: jax.Array,           # int8/fp8 [C, R, B]
     scales: jax.Array,         # f32 [C, B]
@@ -136,21 +139,21 @@ def mscm_grouped_q_level(
     """One tree level through the quantized grouped kernel, fully in-jit.
 
     Mirrors :func:`repro.kernels.ops.mscm_grouped_level` exactly — same
-    device grouping, same gather/mask staging, same unsort — with the
-    quantized kernel in the middle. Traceable inside an enclosing jit.
+    device grouping, same query tiles (``intersect_query_tiles``), same
+    unsort — with the quantized kernel in the middle. Traceable inside an
+    enclosing jit.
     """
     interp = _auto_interpret(interpret)
     c, _, b = vals.shape
     tile_chunk, tile_src, order, flat_pos = group_blocks_device(
         block_c, qt, c
     )
-    safe_src = jnp.maximum(tile_src, 0)                  # [T, QT]
-    bq = block_q[safe_src]                               # [T, QT]
-    r = rows[tile_chunk]                                 # [T, R]
-    xg = x_dense[bq[..., None], r[:, None, :]]           # [T, QT, R]
-    xg = jnp.where((tile_src >= 0)[..., None], xg, 0.0)
+    xg = intersect_query_tiles(
+        x_idx, x_val, d, rows, block_q, block_c, tile_src
+    )
     ps = None
     if parent_scores is not None:
+        safe_src = jnp.maximum(tile_src, 0)              # [T, QT]
         ps = jnp.where(tile_src >= 0, parent_scores[safe_src], 0.0)
     tiles = mscm_grouped_q(
         xg, vals, scales, tile_chunk, ps, mode=mode, interpret=interp
@@ -160,10 +163,12 @@ def mscm_grouped_q_level(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("qt", "mode", "interpret")
+    jax.jit, static_argnames=("d", "qt", "mode", "interpret")
 )
 def mscm_pallas_grouped_q(
-    x_dense: jax.Array,
+    x_idx: jax.Array,
+    x_val: jax.Array,
+    d: int,
     rows: jax.Array,
     vals: jax.Array,
     scales: jax.Array,
@@ -177,6 +182,6 @@ def mscm_pallas_grouped_q(
 ) -> jax.Array:
     """Jitted entry point mirroring ``ops.mscm_pallas_grouped`` (tests)."""
     return mscm_grouped_q_level(
-        x_dense, rows, vals, scales, block_q, block_c, parent_scores,
+        x_idx, x_val, d, rows, vals, scales, block_q, block_c, parent_scores,
         qt=qt, mode=mode, interpret=interpret,
     )

@@ -47,10 +47,7 @@ import numpy as np
 
 from repro.serving.fleet.rpc import recv_frame, send_frame
 
-_NEEDS_DENSE = (
-    "mscm_dense", "mscm_pallas", "mscm_pallas_pregather",
-    "mscm_pallas_grouped", "mscm_pallas_grouped_q",
-)
+_NEEDS_DENSE = ("mscm_dense", "mscm_pallas", "mscm_pallas_pregather")
 
 
 class PartitionRunner:

@@ -26,17 +26,20 @@ from repro.sparse import random_sparse_csc, random_sparse_csr
 from tests.conftest import assert_cross_method_close
 
 
-def _mk(rng, n, d, C, B, nnz_w, nnz_x, A):
+def _mk(rng, n, d, C, B, nnz_w, nnz_x, A, *, ell=False):
+    """Random chunked layer, queries and blocks; with ``ell`` the ELL
+    queries (x_idx, x_val) lead the tuple."""
     w = random_sparse_csc(d, C * B, nnz_w, rng, sibling_groups=B)
     ch = ChunkedLayer.from_csc(w, B)
     x = random_sparse_csr(n, d, nnz_x, rng)
-    xi, xv = x.to_ell()
-    xd = M.scatter_dense(jnp.asarray(xi), jnp.asarray(xv), d)
+    xi, xv = map(jnp.asarray, x.to_ell())
+    xd = M.scatter_dense(xi, xv, d)
     bq = rng.integers(0, n, size=A).astype(np.int32)
     bc = rng.integers(0, C, size=A).astype(np.int32)
     rows, vals = jnp.asarray(ch.rows), jnp.asarray(ch.vals)
     want = np.asarray(ref_lib.mscm_ref(xd, rows, vals, jnp.asarray(bq), jnp.asarray(bc)))
-    return xd, rows, vals, bq, bc, want
+    out = (xd, rows, vals, bq, bc, want)
+    return (xi, xv) + out if ell else out
 
 
 @pytest.mark.parametrize("variant", ["fused", "pregather"])
@@ -71,24 +74,34 @@ def test_pallas_duplicate_chunks_revisit(rng):
 
 @pytest.mark.parametrize("qt", [2, 4, 8])
 def test_grouped_kernel(rng, qt):
-    xd, rows, vals, bq, bc, want = _mk(rng, n=7, d=72, C=5, B=8, nnz_w=7, nnz_x=11, A=17)
-    got = ops.mscm_pallas_grouped(xd, rows, vals, bq, bc, qt=qt, interpret=True)
+    xi, xv, _, rows, vals, bq, bc, want = _mk(
+        rng, n=7, d=72, C=5, B=8, nnz_w=7, nnz_x=11, A=17, ell=True
+    )
+    got = ops.mscm_pallas_grouped(
+        xi, xv, 72, rows, vals, bq, bc, qt=qt, interpret=True
+    )
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
 
 
 def test_grouped_bitwise_vs_dense_lookup(rng):
     """The grouped kernel's per-block result is the dense-lookup einsum's to
     a few ulp: the two sum the R terms in different orders."""
-    xd, rows, vals, bq, bc, _ = _mk(rng, n=6, d=90, C=4, B=8, nnz_w=8, nnz_x=10, A=13)
+    xi, xv, xd, rows, vals, bq, bc, _ = _mk(
+        rng, n=6, d=90, C=4, B=8, nnz_w=8, nnz_x=10, A=13, ell=True
+    )
     dense = M.mscm_dense_lookup(xd, rows, vals, jnp.asarray(bq), jnp.asarray(bc))
-    got = ops.mscm_pallas_grouped(xd, rows, vals, bq, bc, qt=4, interpret=True)
+    got = ops.mscm_pallas_grouped(
+        xi, xv, 90, rows, vals, bq, bc, qt=4, interpret=True
+    )
     assert_cross_method_close(got, dense)
 
 
 @pytest.mark.parametrize("mode", ["prod", "logsum"])
 def test_grouped_fused_epilogue(rng, mode):
     """σ⊗parent epilogue fused in-kernel == epilogue applied to raw logits."""
-    xd, rows, vals, bq, bc, _ = _mk(rng, n=6, d=90, C=4, B=8, nnz_w=8, nnz_x=10, A=13)
+    xi, xv, xd, rows, vals, bq, bc, _ = _mk(
+        rng, n=6, d=90, C=4, B=8, nnz_w=8, nnz_x=10, A=13, ell=True
+    )
     ps = jnp.asarray(rng.random(13).astype(np.float32))
     raw = M.mscm_dense_lookup(xd, rows, vals, jnp.asarray(bq), jnp.asarray(bc))
     if mode == "prod":
@@ -96,11 +109,13 @@ def test_grouped_fused_epilogue(rng, mode):
     else:
         want = jax.nn.log_sigmoid(raw) + ps[:, None]
     got = ops.mscm_pallas_grouped(
-        xd, rows, vals, bq, bc, ps, qt=4, mode=mode, interpret=True
+        xi, xv, 90, rows, vals, bq, bc, ps, qt=4, mode=mode, interpret=True
     )
     assert_cross_method_close(got, want)
     # bitwise against the same kernel's raw logits, epilogue applied outside
-    raw_k = ops.mscm_pallas_grouped(xd, rows, vals, bq, bc, qt=4, interpret=True)
+    raw_k = ops.mscm_pallas_grouped(
+        xi, xv, 90, rows, vals, bq, bc, qt=4, interpret=True
+    )
     if mode == "prod":
         want_k = jax.nn.sigmoid(raw_k) * ps[:, None]
     else:
@@ -146,6 +161,66 @@ def test_group_blocks_device_matches_host(rng, qt):
         assert (tc[nreal:] == want_c[-1]).all()
         # flat_pos round-trips each sorted block to its tile slot
         np.testing.assert_array_equal(ts.reshape(-1)[flat_pos], order)
+
+
+def _dense_tile_oracle(xi, xv, d, rows, bq, bc, qt):
+    """The grouped path's former tile builder: a scalar gather from the
+    dense [n, d+1] table, padding slots masked to zero."""
+    x_dense = M.scatter_dense(xi, xv, d)
+    tile_chunk, tile_src, _, _ = ops.group_blocks_device(bc, qt, rows.shape[0])
+    q = bq[jnp.maximum(tile_src, 0)]                     # [T, QT]
+    r = rows[tile_chunk]                                 # [T, R]
+    xg = x_dense[q[..., None], r[:, None, :]]            # [T, QT, R]
+    return jnp.where((tile_src >= 0)[..., None], xg, 0.0), tile_src
+
+
+@pytest.mark.parametrize("qt", [1, 8])
+@pytest.mark.parametrize("width", ["below_nnz", "above_nnz"])
+@pytest.mark.parametrize("dup", [False, True], ids=["distinct", "dup_id"])
+def test_intersect_query_tiles_bitwise_vs_dense_gather(rng, qt, width, dup):
+    """The intersection tile builder equals the dense-table gather bit for
+    bit: sentinel-padded queries and chunk rows read zero, padding slots
+    are exact zeros, a query with a duplicated id sums as the table's
+    ``.add`` scatter does, and ELL truncation below the query's nnz reads
+    only what the table would hold."""
+    from repro.sparse.csr import rows_to_ell
+
+    n, d, C, B, nnz_x = 9, 120, 6, 8, 14
+    w = random_sparse_csc(d, C * B, 10, rng, sibling_groups=B)
+    ch = ChunkedLayer.from_csc(w, B)
+    rows = jnp.asarray(ch.rows)
+    assert (np.asarray(rows) == d).any(), "chunk rows must hold sentinels"
+    x = random_sparse_csr(n, d, nnz_x, rng)
+    nnz = np.diff(x.indptr)
+    q = int(nnz.min()) - 2 if width == "below_nnz" else int(nnz.max()) + 5
+    xi, xv = rows_to_ell(x, np.arange(n), q)
+    if width == "above_nnz":
+        assert (xi == d).any(), "queries must hold sentinels"
+    if dup:
+        # Query 0 holds one id it shares with a chunk row in two slots.
+        h = np.intersect1d(xi[0][xi[0] < d], np.asarray(rows))[0]
+        p = int(np.flatnonzero(xi[0] == h)[0])
+        other = 1 if p == 0 else 0
+        xi[0, other] = h
+    xi, xv = jnp.asarray(xi), jnp.asarray(xv)
+    a = 29
+    bq = jnp.asarray(rng.integers(0, n, size=a).astype(np.int32))
+    bc = jnp.asarray(rng.integers(0, C, size=a).astype(np.int32))
+    if dup:
+        bq = bq.at[0].set(0)
+        bc = bc.at[0].set(int(np.flatnonzero((np.asarray(rows) == h).any(1))[0]))
+    want, tile_src = _dense_tile_oracle(xi, xv, d, rows, bq, bc, qt)
+    got = ops.intersect_query_tiles(xi, xv, d, rows, bq, bc, tile_src)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    pad = np.asarray(tile_src) < 0
+    if qt > 1:
+        assert pad.any(), "the case must hold padding slots"
+    assert (np.asarray(got)[pad] == 0).all()
+    assert np.count_nonzero(np.asarray(got)) > 0
+    if dup:
+        v = np.asarray(xv)[0]
+        assert np.float32(v[p] + v[other]) in np.asarray(got)
 
 
 def test_unsort_is_gather_inverse(rng):

@@ -4,7 +4,8 @@ No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology, and Mosaic refuses here what it would refuse on the chip (block
 tiling, VMEM limits). Widths are amazon-670k's (paper Table 5) at branching
 32: R = 496 rows per chunk, B = 32 columns, a 64-query bucket at beam 10,
-query tiles of 8. Every call passes ``interpret=False`` explicitly, since
+query tiles of 8, queries in ELL rows of 256; the grouped levels also
+compile at wiki-500k's feature dimension, which no dense table reaches. Every call passes ``interpret=False`` explicitly, since
 the backend here is the CPU.
 
 The topology is described inside a fixture, never at import time: only one
@@ -24,6 +25,8 @@ from repro.kernels.mscm_kernel import mscm_pregather
 from repro.quant.kernels import mscm_grouped_q_level
 
 D = 135_909          # amazon-670k feature dimension
+D_WIKI = 2_381_304   # wiki-500k feature dimension
+Q = 256              # ELL width of a query (ServeConfig.ell_width)
 C, R, B = 1024, 496, 32
 N, BEAM, QT = 64, 10, 8
 A = N * BEAM         # active (query, parent) blocks per level
@@ -67,35 +70,56 @@ def _assert_kernel(compiled, name=None):
         )
 
 
-def test_grouped_compiles_for_v5e(one_chip):
-    def level(x, rows, vals, bq, bc, ps):
+def _compile_grouped(sharding, d):
+    def level(xi, xv, rows, vals, bq, bc, ps):
         return ops.mscm_grouped_level(
-            x, rows, vals, bq, bc, ps, qt=QT, mode="prod", interpret=False
-        )
-
-    s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
-    compiled = jax.jit(level).lower(
-        s((N, D + 1), jnp.float32), s((C, R), jnp.int32),
-        s((C, R, B), jnp.float32), s((A,), jnp.int32), s((A,), jnp.int32),
-        s((A,), jnp.float32),
-    ).compile()
-    _assert_kernel(compiled, "mscm_grouped")
-
-
-def test_grouped_int8_compiles_for_v5e(one_chip):
-    def level(x, rows, vals, scales, bq, bc, ps):
-        return mscm_grouped_q_level(
-            x, rows, vals, scales, bq, bc, ps, qt=QT, mode="prod",
+            xi, xv, d, rows, vals, bq, bc, ps, qt=QT, mode="prod",
             interpret=False,
         )
 
-    s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
-    compiled = jax.jit(level).lower(
-        s((N, D + 1), jnp.float32), s((C, R), jnp.int32),
+    s = lambda shape, dt: _spec(sharding, shape, dt)  # noqa: E731
+    return jax.jit(level).lower(
+        s((N, Q), jnp.int32), s((N, Q), jnp.float32), s((C, R), jnp.int32),
+        s((C, R, B), jnp.float32), s((A,), jnp.int32), s((A,), jnp.int32),
+        s((A,), jnp.float32),
+    ).compile()
+
+
+def _compile_grouped_int8(sharding, d):
+    def level(xi, xv, rows, vals, scales, bq, bc, ps):
+        return mscm_grouped_q_level(
+            xi, xv, d, rows, vals, scales, bq, bc, ps, qt=QT, mode="prod",
+            interpret=False,
+        )
+
+    s = lambda shape, dt: _spec(sharding, shape, dt)  # noqa: E731
+    return jax.jit(level).lower(
+        s((N, Q), jnp.int32), s((N, Q), jnp.float32), s((C, R), jnp.int32),
         s((C, R, B), jnp.int8), s((C, B), jnp.float32), s((A,), jnp.int32),
         s((A,), jnp.int32), s((A,), jnp.float32),
     ).compile()
-    _assert_kernel(compiled, "mscm_grouped_q")
+
+
+def test_grouped_compiles_for_v5e(one_chip):
+    _assert_kernel(_compile_grouped(one_chip, D), "mscm_grouped")
+
+
+def test_grouped_int8_compiles_for_v5e(one_chip):
+    _assert_kernel(_compile_grouped_int8(one_chip, D), "mscm_grouped_q")
+
+
+@pytest.mark.parametrize(
+    "build, kernel",
+    [(_compile_grouped, "mscm_grouped"),
+     (_compile_grouped_int8, "mscm_grouped_q")],
+    ids=["exact", "int8"],
+)
+def test_grouped_compiles_for_v5e_at_wiki_d(one_chip, build, kernel):
+    """At d = 2.38M the compiled level holds no [n, d+1] array (a 64-query
+    table would be 610 MB of f32)."""
+    compiled = build(one_chip, D_WIKI)
+    _assert_kernel(compiled, kernel)
+    assert str(D_WIKI + 1) not in compiled.as_text()
 
 
 def test_pregather_compiles_for_v5e(one_chip):

@@ -162,6 +162,35 @@ def test_grouped_fully_jitted(small_tree):
         assert _tree_infer._cache_size() == size_after_first
 
 
+def test_grouped_program_holds_no_dense_table(small_tree):
+    """The grouped program builds its query tiles from the ELL queries: the
+    lowered ``_tree_infer`` holds no f32 [n, d+1] table and no scatter. The
+    dense-lookup program, which keeps its table, is the control."""
+    import re
+
+    import jax
+
+    from repro.core.tree import _tree_infer
+
+    tree, ws, x, xi, xv = small_tree
+    n, d = xi.shape[0], tree.d
+    table = re.compile(rf"tensor<{n}x{d + 1}xf32>")
+
+    def lowered(method):
+        return jax.jit(
+            lambda a, b: _tree_infer(
+                tuple(tree.layers), tree.n_cols, tree.branching, d, a, b,
+                beam=4, topk=3, method=method, score_mode="prod", qt=4,
+            )
+        ).lower(xi, xv).as_text()
+
+    dense = lowered("mscm_dense")
+    assert table.search(dense) and "stablehlo.scatter" in dense
+    grouped = lowered("mscm_pallas_grouped")
+    assert not table.search(grouped)
+    assert "scatter" not in grouped
+
+
 def test_nonuniform_branching(rng):
     d = 90
     ws = make_tree_weights(rng, d, [4, 32], 8)  # level branchings 4 then 8
