@@ -71,7 +71,7 @@ def grouped_report(ds: str = "eurlex-4k", branching: int = 8, *, qt: int = 8,
     """Device-grouped MXU path: per-level tile accounting + batch timing.
 
     The grouped kernel's win is structural: the fused kernel walks a grid of
-    A blocks (one [1,R]×[R,B] contraction each), while the grouped kernel
+    A blocks (one [1,R]·[B,R]ᵀ contraction each), while the grouped kernel
     packs the same blocks chunk-major into QT-row tiles — per level it runs
     ``tiles ≤ A/QT + C`` matmuls (each chunk wastes at most one ragged
     tile), amortizing every chunk's DMA over up to QT queries. This report

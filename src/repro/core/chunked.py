@@ -7,12 +7,23 @@ TPU-native translation here is a per-chunk **ELL tile**:
 
     rows : int32 [C, R]      union of the sibling row supports, sorted and
                              padded with the sentinel ``d``
-    vals : f32   [C, R, B]   dense (R × B) value tile per chunk; positions
-                             where a sibling lacks an entry hold explicit 0
+    vals : f32   [C, B, R]   dense (B × R) value tile per chunk, one row per
+                             sibling column; positions where a sibling lacks
+                             an entry, and the padding rows, hold explicit 0
 
 The sibling-support-similarity observation (paper Item 2) is what makes the
-``[R, B]`` tile dense enough to be profitable: R ≈ max-union-support per
+``[B, R]`` tile dense enough to be profitable: R ≈ max-union-support per
 chunk rather than B × per-column-support.
+
+R is padded to a multiple of :data:`LANES`, the TPU's lane width, and sits
+last, on the lanes. A TPU stores an array's two minor dims in (8, 128)
+tiles; with B = 32 last, and R = 496 not a multiple of 128, XLA would
+give the array a layout of its own and copy it, with B padded to 128
+lanes, into the layout the kernels read on every call. ``[C, B, R]`` with R
+a multiple of 128 is already that layout, so no call copies the tiles.
+Padding costs 3% at R = 496 (to 512); a tree whose union width lies far
+below 128 would store mostly zeros, and no configuration served here has
+one.
 
 Shapes are static once a model is loaded, which is what makes the whole beam
 search jit-able with no dynamic sparsity in the control path.
@@ -27,8 +38,18 @@ import numpy as np
 from repro.sparse.csr import CSC
 
 
+#: The TPU lane width: the chunk tiles' row axis R is padded to a multiple.
+LANES = 128
+
+
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def lane_width(n: int) -> int:
+    """Width of a tile row axis that holds ``n`` rows: ``n`` rounded up to
+    a multiple of :data:`LANES`, and at least one lane row."""
+    return _round_up(max(1, n), LANES)
 
 
 @dataclasses.dataclass
@@ -36,7 +57,7 @@ class ChunkedLayer:
     """One tree level's weight matrix in chunked (MSCM) format."""
 
     rows: np.ndarray  # int32 [C, R], sentinel-padded (sentinel == d)
-    vals: np.ndarray  # f32   [C, R, B]
+    vals: np.ndarray  # f32   [C, B, R]
     d: int            # feature dimension
     B: int            # branching factor == columns per chunk
 
@@ -63,16 +84,15 @@ class ChunkedLayer:
         cls,
         w: CSC,
         branching: int,
-        *,
-        row_align: int = 8,
-        min_width: int = 8,
     ) -> "ChunkedLayer":
         """Convert a CSC weight matrix to chunked format.
 
         Columns [i*B, (i+1)*B) form chunk i (labels are laid out in tree
         order, so siblings are contiguous — paper eq. 7). The last chunk is
         zero-padded if L % B != 0. R is the max over chunks of the union
-        support size, rounded up to ``row_align`` (f32 sublane alignment).
+        support size, rounded up to :data:`LANES` (see the module
+        docstring); padding rows hold the sentinel ``d`` and zeros.
+        The tiles are written in their ``[C, B, R]`` layout directly.
         """
         d, L = w.shape
         B = int(branching)
@@ -80,7 +100,7 @@ class ChunkedLayer:
         # vectorized per chunk: union of sibling supports via np.unique, then
         # scatter values at searchsorted positions (no per-entry Python loop)
         unions = []
-        width = min_width
+        width = 0
         col_start = w.indptr
         for c in range(C):
             lo = col_start[c * B]
@@ -88,9 +108,9 @@ class ChunkedLayer:
             idx = np.unique(w.indices[lo:hi])
             unions.append(idx.astype(np.int32))
             width = max(width, len(idx))
-        R = _round_up(width, row_align)
+        R = lane_width(width)
         rows = np.full((C, R), d, dtype=np.int32)
-        vals = np.zeros((C, R, B), dtype=np.float32)
+        vals = np.zeros((C, B, R), dtype=np.float32)
         for c, idx in enumerate(unions):
             rows[c, : len(idx)] = idx
             lo = col_start[c * B]
@@ -102,7 +122,7 @@ class ChunkedLayer:
             reps = np.diff(col_start[c * B : c * B + n_cols + 1]).astype(np.int64)
             ent_cols = np.repeat(np.arange(n_cols), reps)
             pos = np.searchsorted(idx, ent_rows)
-            vals[c, pos, ent_cols] = ent_vals
+            vals[c, ent_cols, pos] = ent_vals
         return cls(rows=rows, vals=vals, d=d, B=B)
 
     def to_dense(self) -> np.ndarray:
@@ -110,14 +130,14 @@ class ChunkedLayer:
         out = np.zeros((self.d + 1, self.n_cols), dtype=np.float32)
         for c in range(self.C):
             cols = slice(c * self.B, (c + 1) * self.B)
-            np.add.at(out, (self.rows[c], cols), self.vals[c])
+            np.add.at(out, (self.rows[c], cols), self.vals[c].T)
         return out[: self.d]
 
     def memory_bytes(self) -> int:
         return self.rows.nbytes + self.vals.nbytes
 
     def occupancy(self) -> float:
-        """Fraction of the [C,R,B] tile holding true nonzeros (Item 2 metric)."""
+        """Fraction of the [C,B,R] tile holding true nonzeros (Item 2 metric)."""
         return float((self.vals != 0).mean())
 
 

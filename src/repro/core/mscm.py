@@ -14,7 +14,7 @@ Iterator variants (paper §4 items 1-4, TPU-adapted — see DESIGN.md §2):
 
 * ``mscm_dense_lookup``  — dense-lookup analogue: queries pre-scattered into a
   dense [n, d+1] table; per-block gather at the chunk's ELL rows + one
-  [R]×[R,B] contraction. One traversal *per chunk*.
+  [R]·[B,R]ᵀ contraction. One traversal *per chunk*.
 * ``mscm_searchsorted``  — binary-search analogue: vectorized searchsorted of
   the chunk's row list into the query's sorted nnz list (fixed log₂ depth).
   No dense table required.
@@ -57,14 +57,14 @@ def scatter_dense(x_idx: jax.Array, x_val: jax.Array, d: int) -> jax.Array:
 def mscm_dense_lookup(
     x_dense: jax.Array,   # f32 [n, d+1]
     rows: jax.Array,      # int32 [C, R]
-    vals: jax.Array,      # f32 [C, R, B]
+    vals: jax.Array,      # f32 [C, B, R]
     block_q: jax.Array,   # int32 [A]
     block_c: jax.Array,   # int32 [A]
 ) -> jax.Array:
     """Dense-lookup MSCM: gather query values at chunk rows, contract."""
     r = rows[block_c]                                   # [A, R]
     xg = x_dense[block_q[:, None], r]                   # [A, R]  (gather)
-    return jnp.einsum("ar,arb->ab", xg, vals[block_c], precision=F32)  # [A, B]
+    return jnp.einsum("ar,abr->ab", xg, vals[block_c], precision=F32)  # [A, B]
 
 
 def gather_query_rows(
@@ -84,7 +84,7 @@ def mscm_searchsorted(
     x_idx: jax.Array,     # int32 [n, Q] sorted, sentinel-padded (== d)
     x_val: jax.Array,     # f32 [n, Q]
     rows: jax.Array,      # int32 [C, R]
-    vals: jax.Array,      # f32 [C, R, B]
+    vals: jax.Array,      # f32 [C, B, R]
     block_q: jax.Array,   # int32 [A]
     block_c: jax.Array,   # int32 [A]
     d: int,
@@ -103,7 +103,7 @@ def mscm_searchsorted(
     pos_c = jnp.minimum(pos, q - 1)
     hit = (jnp.take_along_axis(xi, pos_c, axis=1) == r) & (r < d)
     xg = jnp.where(hit, jnp.take_along_axis(xv, pos_c, axis=1), 0.0)
-    return jnp.einsum("ar,arb->ab", xg, vals[block_c], precision=F32)
+    return jnp.einsum("ar,abr->ab", xg, vals[block_c], precision=F32)
 
 
 def vanilla_columns(

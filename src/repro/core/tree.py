@@ -50,17 +50,17 @@ from repro.sparse.csr import CSC
 #   mscm_searchsorted     binary-search MSCM (paper §4 item 2): no dense
 #                         table, log₂(Q)-depth intersections. Best when d is
 #                         huge or memory-tight; slower than dense per block.
-#   mscm_pallas           Pallas fused kernel: one [1,R]×[R,B] contraction
+#   mscm_pallas           Pallas fused kernel: one [1,R]·[B,R]ᵀ contraction
 #                         per block, in-kernel VMEM gather, chunk-sorted grid
 #                         so each chunk tile is DMA'd once (paper Alg. 3).
 #                         Interpret mode only: Mosaic refuses its in-kernel
 #                         1-D gather, so on TPU it raises NotImplementedError
 #                         (for d ≤ ~1M; larger d takes the pregather kernel).
 #   mscm_pallas_pregather Pallas pregather kernel: XLA gathers query rows in
-#                         HBM, kernel streams [1,R]×[R,B]. The huge-d TPU
+#                         HBM, kernel streams [1,R]·[B,R]ᵀ. The huge-d TPU
 #                         path (enterprise d = 4M).
 #   mscm_pallas_grouped   MXU-tiled grouped kernel: blocks packed per chunk
-#                         into QT-row tiles *on device*, one [QT,R]×[R,B]
+#                         into QT-row tiles *on device*, one [QT,R]·[B,R]ᵀ
 #                         matmul per tile with the σ⊗parent beam epilogue
 #                         fused in-kernel. The high-throughput batch TPU
 #                         path — amortizes each chunk tile over up to QT
@@ -91,7 +91,7 @@ class TreeLayerArrays:
     """Device-resident tensors for one level (a pytree)."""
 
     chunk_rows: jax.Array  # int32 [C, R]
-    chunk_vals: jax.Array  # f32 [C, R, B]
+    chunk_vals: jax.Array  # f32 [C, B, R], R a multiple of 128 (core.chunked)
     col_rows: jax.Array    # int32 [L, Rc] (vanilla baseline layout)
     col_vals: jax.Array    # f32 [L, Rc]
 

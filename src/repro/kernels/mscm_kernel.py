@@ -12,12 +12,12 @@ Kernels
 -------
 ``fused``      dense-lookup analogue for small/medium d: the query's dense
                row lives in VMEM, the gather at the chunk's ELL rows happens
-               in-kernel, followed by a [1,R]×[R,B] contraction.
+               in-kernel, followed by a [1,R]·[B,R]ᵀ contraction.
 ``pregather``  huge-d path (e.g. enterprise d = 4M, a dense row would blow
                VMEM): XLA gathers x at chunk rows in HBM, the kernel streams
                the pre-gathered [A, R] rows against chunk tiles.
 ``grouped``    MXU-tiled batch path: blocks grouped per chunk into query
-               tiles of QT rows → one [QT,R]×[R,B] matmul per tile, with the
+               tiles of QT rows → one [QT,R]·[B,R]ᵀ matmul per tile, with the
                beam-search epilogue (σ(logit) ⊗ parent score, paper eq. 5)
                optionally fused into the kernel body so logits never
                round-trip through HBM between matmul and beam step. Grouping
@@ -31,11 +31,17 @@ Alignment notes (TPU target; interpret mode ignores these):
   (8, 128) or equal to the array's own; per-row operands therefore carry a
   unit middle dim (``[A, 1, R]``, ``[T, QT, 1]``) instead of a ``(1, R)``
   block over ``[A, R]``.
-* R is padded to a multiple of 8 by ``ChunkedLayer.from_csc`` (f32 sublanes).
-* B is the lane dimension of the chunk tile; B < 128 underutilizes lanes —
-  the grouped kernel's tiles put QT on sublanes to compensate. XLA stores a
-  ``[C, R, B<128]`` array in a lane-dense layout and copies it into the
-  kernel's padded ``[R, 128]`` tiling on every call.
+* Chunk tiles are stored ``[C, B, R]`` with R padded to a multiple of 128
+  by ``ChunkedLayer.from_csc``, and every kernel contracts a query row
+  ``[., R]`` with a tile ``[B, R]`` over their last axes. The reason is
+  the layout XLA gives the stored array: a ``[C, R, B<128]`` array (or a
+  ``[C, B, R]`` one with R not a multiple of 128) is laid out with C on the
+  lanes, and XLA copies it into the kernel's row-major (8, 128) tiling, B
+  padded to 128 lanes, on every call: 4x the tile bytes written, and the
+  largest op of every dispatch. ``[C, B, R % 128 == 0]`` is already the
+  kernel's layout, so the tiles are read where they lie.
+* B < 128 underutilizes the MXU's output lanes; the grouped kernel's tiles
+  put QT query rows on sublanes to compensate.
 * The fused kernel's in-kernel 1-D gather (``jnp.take``) does not lower on
   Mosaic ("Only 2D gather is supported"): it runs in interpret mode only,
   and ``ops.mscm_pallas`` refuses it on a TPU backend.
@@ -54,6 +60,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.mscm import F32
 
+#: ``[M, R] · [B, R]ᵀ -> [M, B]``: a query row block against a chunk tile,
+#: contracted over the lane axis R of both.
+CONTRACT_R = (((1,), (1,)), ((), ()))
+
 
 # ---------------------------------------------------------------------------
 # fused: in-kernel gather from a VMEM-resident dense query row
@@ -65,7 +75,7 @@ def _fused_body(bq_ref, bc_ref, x_ref, rows_ref, vals_ref, out_ref):
     xg = jnp.take(x_ref[0, :], r, mode="clip")           # [R] VMEM gather
     acc = jax.lax.dot_general(
         xg[None, :], vals_ref[0],
-        dimension_numbers=(((1,), (0,)), ((), ())),
+        dimension_numbers=CONTRACT_R,
         precision=F32,
         preferred_element_type=jnp.float32,
     )                                                    # [1, B]
@@ -75,7 +85,7 @@ def _fused_body(bq_ref, bc_ref, x_ref, rows_ref, vals_ref, out_ref):
 def mscm_fused(
     x_dense: jax.Array,   # f32 [n, Dp]  (Dp >= d+1; sentinel slot is zero)
     rows: jax.Array,      # int32 [C, R]
-    vals: jax.Array,      # f32 [C, R, B]
+    vals: jax.Array,      # f32 [C, B, R]
     block_q: jax.Array,   # int32 [A]  sorted by block_c for chunk reuse
     block_c: jax.Array,   # int32 [A]
     *,
@@ -83,14 +93,14 @@ def mscm_fused(
 ) -> jax.Array:
     a = block_q.shape[0]
     _, dp = x_dense.shape
-    c, r, b = vals.shape
+    c, b, r = vals.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(a,),
         in_specs=[
             pl.BlockSpec((1, dp), lambda i, bq, bc: (bq[i], 0)),
             pl.BlockSpec((1, r), lambda i, bq, bc: (bc[i], 0)),
-            pl.BlockSpec((1, r, b), lambda i, bq, bc: (bc[i], 0, 0)),
+            pl.BlockSpec((1, b, r), lambda i, bq, bc: (bc[i], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, b), lambda i, bq, bc: (i, 0)),
     )
@@ -103,14 +113,14 @@ def mscm_fused(
 
 
 # ---------------------------------------------------------------------------
-# pregather: XLA does the HBM gather, kernel streams [1,R] x [R,B]
+# pregather: XLA does the HBM gather, kernel streams [1,R] x [B,R]^T
 # ---------------------------------------------------------------------------
 
 def _pregather_body(bc_ref, xg_ref, vals_ref, out_ref):
     del bc_ref
     acc = jax.lax.dot_general(
         xg_ref[0], vals_ref[0],
-        dimension_numbers=(((1,), (0,)), ((), ())),
+        dimension_numbers=CONTRACT_R,
         precision=F32,
         preferred_element_type=jnp.float32,
     )                                                    # [1, B]
@@ -119,13 +129,13 @@ def _pregather_body(bc_ref, xg_ref, vals_ref, out_ref):
 
 def mscm_pregather(
     xg: jax.Array,        # f32 [A, R]  pre-gathered query values
-    vals: jax.Array,      # f32 [C, R, B]
+    vals: jax.Array,      # f32 [C, B, R]
     block_c: jax.Array,   # int32 [A] sorted
     *,
     interpret: bool = False,
 ) -> jax.Array:
     a, r = xg.shape
-    c, _, b = vals.shape
+    c, b, _ = vals.shape
     # Rows ride as [A, 1, R] / [A, 1, B] so every block's last two dims equal
     # the array's (a (1, R) block over [A, R] breaks Mosaic's (8, 128) rule).
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -133,7 +143,7 @@ def mscm_pregather(
         grid=(a,),
         in_specs=[
             pl.BlockSpec((1, 1, r), lambda i, bc: (i, 0, 0)),
-            pl.BlockSpec((1, r, b), lambda i, bc: (bc[i], 0, 0)),
+            pl.BlockSpec((1, b, r), lambda i, bc: (bc[i], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, b), lambda i, bc: (i, 0, 0)),
     )
@@ -186,7 +196,7 @@ def _grouped_body(tc_ref, xg_ref, ps_ref, vals_ref, out_ref, *, mode):
     del tc_ref
     acc = jax.lax.dot_general(
         xg_ref[0], vals_ref[0],
-        dimension_numbers=(((1,), (0,)), ((), ())),
+        dimension_numbers=CONTRACT_R,
         precision=F32,
         preferred_element_type=jnp.float32,
     )                                                    # [QT, B]
@@ -199,7 +209,7 @@ def _grouped_body(tc_ref, xg_ref, ps_ref, vals_ref, out_ref, *, mode):
 
 def mscm_grouped(
     xg_tiles: jax.Array,   # f32 [T, QT, R] gathered query rows per tile
-    vals: jax.Array,       # f32 [C, R, B]
+    vals: jax.Array,       # f32 [C, B, R]
     tile_chunk: jax.Array,  # int32 [T]
     parent_scores: Optional[jax.Array] = None,  # f32 [T, QT] beam scores
     *,
@@ -217,7 +227,7 @@ def mscm_grouped(
     the combined beam scores are the only thing written back to HBM.
     """
     t, qt, r = xg_tiles.shape
-    c, _, b = vals.shape
+    c, b, _ = vals.shape
     if mode not in ("none", "prod", "logsum"):
         raise ValueError(f"unknown epilogue mode {mode!r}")
     if parent_scores is None:
@@ -235,7 +245,7 @@ def mscm_grouped(
         in_specs=[
             pl.BlockSpec((1, qt, r), lambda i, tc: (i, 0, 0)),
             pl.BlockSpec((1, qt, 1), lambda i, tc: (i, 0, 0)),
-            pl.BlockSpec((1, r, b), lambda i, tc: (tc[i], 0, 0)),
+            pl.BlockSpec((1, b, r), lambda i, tc: (tc[i], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, qt, b), lambda i, tc: (i, 0, 0)),
     )

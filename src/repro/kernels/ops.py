@@ -31,7 +31,7 @@ from repro.kernels.mscm_kernel import (
 # VMEM alongside the chunk tile; fall back to the pre-gathered kernel.
 VMEM_ROW_LIMIT = 1 << 20
 
-# Query-tile height of the grouped kernel: rows per [QT, R] x [R, B] matmul.
+# Query-tile height of the grouped kernel: rows per [QT, R] x [B, R]^T matmul.
 DEFAULT_QT = 8
 
 
@@ -152,7 +152,7 @@ def mscm_grouped_level(
     x_val: jax.Array,          # f32 [n, Q]
     d: int,
     rows: jax.Array,           # int32 [C, R]
-    vals: jax.Array,           # f32 [C, R, B]
+    vals: jax.Array,           # f32 [C, B, R]
     block_q: jax.Array,        # int32 [A]
     block_c: jax.Array,        # int32 [A]
     parent_scores: Optional[jax.Array] = None,  # f32 [A] (beam scores)
@@ -165,14 +165,14 @@ def mscm_grouped_level(
 
     Groups the active blocks chunk-major on device, builds the [T, QT, R]
     query tiles by intersecting the ELL queries with the chunk rows
-    (:func:`intersect_query_tiles`), runs one [QT, R] x [R, B] matmul per
+    (:func:`intersect_query_tiles`), runs one [QT, R] x [B, R]^T matmul per
     tile with the beam epilogue fused (``mode`` — see
     :func:`mscm_grouped`), and returns the [A, B] block scores in the
     original block order via a pure-gather unsort. Traceable: safe to call
     inside an enclosing jit.
     """
     interp = _auto_interpret(interpret)
-    c, _, b = vals.shape
+    c, b, _ = vals.shape
     tile_chunk, tile_src, order, flat_pos = group_blocks_device(
         block_c, qt, c
     )
@@ -203,7 +203,7 @@ def mscm_grouped_level(
 def mscm_pallas(
     x_dense: jax.Array,   # f32 [n, Dp]
     rows: jax.Array,      # int32 [C, R]
-    vals: jax.Array,      # f32 [C, R, B]
+    vals: jax.Array,      # f32 [C, B, R]
     block_q: jax.Array,   # int32 [A]
     block_c: jax.Array,   # int32 [A]
     *,
@@ -258,7 +258,7 @@ def mscm_pallas_grouped(
     """Batch-mode MXU-tiled MSCM, grouped *on device* — one XLA program.
 
     Blocks are packed per chunk into QT-row tiles in-jit
-    (:func:`group_blocks_device`); one [QT, R] x [R, B] matmul per tile, with
+    (:func:`group_blocks_device`); one [QT, R] x [B, R]^T matmul per tile, with
     the beam epilogue fused when ``mode`` is ``prod``/``logsum``. Returns
     f32 [A, B] in the original block order.
     """

@@ -21,19 +21,19 @@ from repro.core.mscm import F32
 def mscm_ref(
     x_dense: jax.Array,   # f32 [n, d+1] (dense queries incl. sentinel slot)
     rows: jax.Array,      # int32 [C, R] sentinel-padded
-    vals: jax.Array,      # f32 [C, R, B]
+    vals: jax.Array,      # f32 [C, B, R]
     block_q: jax.Array,   # int32 [A]
     block_c: jax.Array,   # int32 [A]
 ) -> jax.Array:
     """Dense oracle: A[a] = (x[block_q[a]] · W)[block_c[a]·B : +B]."""
-    c, r, b = vals.shape
+    c, b, r = vals.shape
     d_plus = x_dense.shape[1]
     # Scatter chunk tiles into the dense [d+1, C*B] weight matrix. Sentinel
     # rows (== d) land in the zero slot of x_dense, contributing nothing.
     w = jnp.zeros((d_plus, c * b), dtype=vals.dtype)
-    col_ids = (jnp.arange(c)[:, None, None] * b + jnp.arange(b)[None, None, :])
-    col_ids = jnp.broadcast_to(col_ids, (c, r, b))
-    row_ids = jnp.broadcast_to(rows[:, :, None], (c, r, b))
+    col_ids = (jnp.arange(c)[:, None, None] * b + jnp.arange(b)[None, :, None])
+    col_ids = jnp.broadcast_to(col_ids, (c, b, r))
+    row_ids = jnp.broadcast_to(rows[:, None, :], (c, b, r))
     w = w.at[row_ids.reshape(-1), col_ids.reshape(-1)].add(vals.reshape(-1))
     w = w.at[d_plus - 1, :].set(0.0)  # sentinel row carries no weight
     full = jnp.matmul(x_dense, w, precision=F32)              # [n, C*B]
@@ -45,11 +45,11 @@ def block_ref_marching(
     x_idx: np.ndarray,     # int32 [nnz_x] sorted query support
     x_val: np.ndarray,     # f32 [nnz_x]
     chunk_rows: np.ndarray,  # int32 [R] sentinel-padded, sorted
-    chunk_vals: np.ndarray,  # f32 [R, B]
+    chunk_vals: np.ndarray,  # f32 [B, R]
     d: int,
 ) -> np.ndarray:
     """Paper Algorithm 2 with the marching-pointer iterator (numpy scalar)."""
-    b = chunk_vals.shape[1]
+    b = chunk_vals.shape[0]
     z = np.zeros(b, dtype=np.float64)
     ix, ik = 0, 0
     nx, nk = len(x_idx), len(chunk_rows)
@@ -58,7 +58,7 @@ def block_ref_marching(
         if jx >= d or jk >= d:
             break
         if jx == jk:
-            z += float(x_val[ix]) * chunk_vals[ik].astype(np.float64)
+            z += float(x_val[ix]) * chunk_vals[:, ik].astype(np.float64)
             ix += 1
             ik += 1
         elif jx < jk:

@@ -55,9 +55,9 @@ def serve_step_spec(batch: int, beam: int, topk: int, mesh):
     for li, size in enumerate(sizes):
         b = BRANCHING[li]
         c = sizes[li - 1] if li else 1
-        r = min(ELL_R, ((LEVEL_NNZ * b + 7) // 8) * 8) if li == 0 else ELL_R
+        r = min(ELL_R, ((LEVEL_NNZ * b + 127) // 128) * 128) if li == 0 else ELL_R
         rows = jax.ShapeDtypeStruct((c, r), jnp.int32)
-        vals = jax.ShapeDtypeStruct((c, r, b), jnp.bfloat16)
+        vals = jax.ShapeDtypeStruct((c, b, r), jnp.bfloat16)
         is_leaf = li == n_levels - 1
         spec_rows = P("model", None) if is_leaf else P()
         spec_vals = P("model", None, None) if is_leaf else P()

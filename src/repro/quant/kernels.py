@@ -4,13 +4,13 @@
 (:func:`repro.kernels.mscm_kernel.mscm_grouped`) with one extra input — the
 per-(chunk, column) scale row — and one extra in-kernel op: the chunk tile
 is widened ``int8 → f32`` and multiplied by its scale row **in VMEM**, right
-before the [QT, R] × [R, B] contraction. Everything else is shared with the
+before the [QT, R] × [B, R]ᵀ contraction. Everything else is shared with the
 exact path: the chunk-major device grouping (``ops.group_blocks_device``),
 the fused σ⊗parent epilogue, the gather-based unsort, and the canonical
 ``beam_select`` downstream — so the quantized tier changes *weight bits*,
 never selection semantics.
 
-HBM traffic per tile drops ~4× on the dominant operand (the [R, B] chunk
+HBM traffic per tile drops ~4× on the dominant operand (the [B, R] chunk
 tile ships as int8; the [B] scale row is noise), which is the whole point:
 the tier trades a bounded score perturbation (|err| ≤ scale/2 per weight,
 measured contract in ``benchmarks/bench_quant.py``) for ~4× memory and
@@ -36,6 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.mscm import F32
+from repro.kernels.mscm_kernel import CONTRACT_R
 from repro.kernels.ops import (
     DEFAULT_QT,
     _auto_interpret,
@@ -54,10 +55,10 @@ def _grouped_q_body(
     # In-register dequant: widen the resident int8/fp8 chunk tile to f32 and
     # apply the per-column scale row while both live in VMEM — the f32 tile
     # never exists in HBM.
-    v = vals_ref[0].astype(jnp.float32) * scale          # [R, B]
+    v = vals_ref[0].astype(jnp.float32) * scale.T        # [B, R]
     acc = jax.lax.dot_general(
         xg_ref[0], v,
-        dimension_numbers=(((1,), (0,)), ((), ())),
+        dimension_numbers=CONTRACT_R,
         precision=F32,
         preferred_element_type=jnp.float32,
     )                                                    # [QT, B]
@@ -70,7 +71,7 @@ def _grouped_q_body(
 
 def mscm_grouped_q(
     xg_tiles: jax.Array,    # f32 [T, QT, R] gathered query rows per tile
-    vals: jax.Array,        # int8/fp8 [C, R, B] quantized chunk tiles
+    vals: jax.Array,        # int8/fp8 [C, B, R] quantized chunk tiles
     scales: jax.Array,      # f32 [C, B] per-(chunk, column) scales
     tile_chunk: jax.Array,  # int32 [T]
     parent_scores: Optional[jax.Array] = None,  # f32 [T, QT] beam scores
@@ -86,7 +87,7 @@ def mscm_grouped_q(
     keeps them VMEM-resident across every query tile that hits the chunk.
     """
     t, qt, r = xg_tiles.shape
-    c, _, b = vals.shape
+    c, b, _ = vals.shape
     if mode not in ("none", "prod", "logsum"):
         raise ValueError(f"unknown epilogue mode {mode!r}")
     if parent_scores is None:
@@ -107,7 +108,7 @@ def mscm_grouped_q(
         in_specs=[
             pl.BlockSpec((1, qt, r), lambda i, tc: (i, 0, 0)),
             pl.BlockSpec((1, qt, 1), lambda i, tc: (i, 0, 0)),
-            pl.BlockSpec((1, r, b), lambda i, tc: (tc[i], 0, 0)),
+            pl.BlockSpec((1, b, r), lambda i, tc: (tc[i], 0, 0)),
             pl.BlockSpec((rows, b), lambda i, tc: (tc[i] // rows, 0)),
         ],
         out_specs=pl.BlockSpec((1, qt, b), lambda i, tc: (i, 0, 0)),
@@ -126,7 +127,7 @@ def mscm_grouped_q_level(
     x_val: jax.Array,          # f32 [n, Q]
     d: int,
     rows: jax.Array,           # int32 [C, R]
-    vals: jax.Array,           # int8/fp8 [C, R, B]
+    vals: jax.Array,           # int8/fp8 [C, B, R]
     scales: jax.Array,         # f32 [C, B]
     block_q: jax.Array,        # int32 [A]
     block_c: jax.Array,        # int32 [A]
@@ -144,7 +145,7 @@ def mscm_grouped_q_level(
     enclosing jit.
     """
     interp = _auto_interpret(interpret)
-    c, _, b = vals.shape
+    c, b, _ = vals.shape
     tile_chunk, tile_src, order, flat_pos = group_blocks_device(
         block_c, qt, c
     )

@@ -1,24 +1,24 @@
 """Quantized ELL chunk storage: int8/fp8 weights + pruned re-pack.
 
 Layout. A :class:`~repro.core.tree.TreeLayerArrays` stores one level's chunk
-tiles as ``chunk_vals`` f32 [C, R, B]. The quantized layer replaces that with
-``chunk_vals`` int8 (or fp8-e4m3) [C, R, B] plus ``chunk_scales`` f32 [C, B]
+tiles as ``chunk_vals`` f32 [C, B, R]. The quantized layer replaces that with
+``chunk_vals`` int8 (or fp8-e4m3) [C, B, R] plus ``chunk_scales`` f32 [C, B]
 — one symmetric scale per (chunk, column), i.e. per tree node, so a dominant
 column cannot flatten its siblings' resolution. ``chunk_rows`` (the ELL row
 indices, the masked-multiplication *mask*) stays exact int32: quantization
 perturbs scores, never the sparsity pattern.
 
-Scales: ``scale[c, b] = max_r |vals[c, r, b]| / Q`` with ``Q = 127`` (int8)
+Scales: ``scale[c, b] = max_r |vals[c, b, r]| / Q`` with ``Q = 127`` (int8)
 or ``448`` (fp8-e4m3 finite max); all-zero columns get scale 1 so dequant is
 exactly 0 (and never divides by zero). For int8, ``q = rint(v / scale)``
 clipped to ±127 — the worst-case dequant error is ``scale / 2`` per weight,
 the bound the hypothesis property pins.
 
 Pruned re-pack (:func:`prune_chunks`): per chunk, keep the top
-``ceil(keep_frac · nnz_c)`` ELL rows by magnitude ``max_b |vals[c, r, :]|``
+``ceil(keep_frac · nnz_c)`` ELL rows by magnitude ``max_b |vals[c, :, r]|``
 (ties break to the lower row index) and re-pack into a narrower pad width
-``R' = round_up(max kept, 8)`` (min 8 — the same f32 sublane alignment
-``ChunkedLayer.from_csc`` applies). Kept weights are **bitwise** the
+``R' = round_up(max kept, 128)`` — the same lane alignment
+``ChunkedLayer.from_csc`` applies. Kept weights are **bitwise** the
 original f32 values when dequantized at the same scale grid; dropped rows
 simply vanish from the mask.
 
@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.chunked import lane_width
 from repro.core.tree import TreeLayerArrays, XMRTree
 
 #: Storage dtypes by name -> (numpy target dtype factory, symmetric qmax).
@@ -56,7 +57,7 @@ class QuantLayerArrays:
     clamping, chunk counts — work on either)."""
 
     chunk_rows: jax.Array    # int32 [C, R]  exact ELL mask (sentinel = d)
-    chunk_vals: jax.Array    # int8/fp8 [C, R, B] quantized weights
+    chunk_vals: jax.Array    # int8/fp8 [C, B, R] quantized weights
     chunk_scales: jax.Array  # f32 [C, B] per-(chunk, column) symmetric scale
 
 
@@ -113,10 +114,10 @@ def quantize_layer(layer: TreeLayerArrays, dtype: str = "int8",
     rows = np.asarray(layer.chunk_rows if rows is None else rows)
     vals = np.asarray(layer.chunk_vals if vals is None else vals,
                       dtype=np.float32)
-    amax = np.abs(vals).max(axis=1)                      # [C, B]
+    amax = np.abs(vals).max(axis=2)                      # [C, B]
     scale = (amax / qmax).astype(np.float32)
     scale = np.where(scale > 0, scale, np.float32(1.0))
-    scaled = vals / scale[:, None, :]
+    scaled = vals / scale[:, :, None]
     if np_dtype is np.int8:
         q = np.clip(np.rint(scaled), -qmax, qmax).astype(np.int8)
     else:
@@ -138,7 +139,7 @@ def dequantize_layer(qlayer: QuantLayerArrays, *, d: int) -> TreeLayerArrays:
     """
     vals = (
         np.asarray(qlayer.chunk_vals).astype(np.float32)
-        * np.asarray(qlayer.chunk_scales)[:, None, :]
+        * np.asarray(qlayer.chunk_scales)[:, :, None]
     )
     return TreeLayerArrays(
         chunk_rows=qlayer.chunk_rows,
@@ -148,26 +149,21 @@ def dequantize_layer(qlayer: QuantLayerArrays, *, d: int) -> TreeLayerArrays:
     )
 
 
-def _round_up(x: int, align: int) -> int:
-    return -(-x // align) * align
-
-
 def prune_chunks(
     rows: np.ndarray,          # int32 [C, R] (sentinel = d)
-    vals: np.ndarray,          # f32 [C, R, B]
+    vals: np.ndarray,          # f32 [C, B, R]
     keep_frac: float,
     *,
     sentinel: int,
-    row_align: int = 8,
-    min_width: int = 8,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Magnitude-pruned ELL re-pack: keep the heavy rows, shrink R.
 
     Per chunk, the top ``ceil(keep_frac · nnz_c)`` rows by
-    ``max_b |vals[c, r, :]|`` survive (stable: ties keep the lower row
+    ``max_b |vals[c, :, r]|`` survive (stable: ties keep the lower row
     index); survivors are re-packed in ascending row order into a fresh pad
-    width ``R' = round_up(max kept, row_align)`` (min ``min_width``). Kept
-    values are copied bitwise; everything else becomes sentinel/0 padding.
+    width ``R' = lane_width(max kept)`` (:func:`repro.core.chunked.lane_width`:
+    a multiple of 128 lanes). Kept values are copied bitwise; everything
+    else becomes sentinel/0 padding.
     """
     if not 0.0 < keep_frac <= 1.0:
         raise ValueError(f"keep_frac must be in (0, 1]; got {keep_frac}")
@@ -175,7 +171,7 @@ def prune_chunks(
     vals = np.asarray(vals)
     c, r = rows.shape
     valid = rows != sentinel                              # [C, R]
-    mag = np.abs(vals).max(axis=2)                        # [C, R]
+    mag = np.abs(vals).max(axis=1)                        # [C, R]
     mag = np.where(valid, mag, -1.0)                      # padding never kept
     nnz = valid.sum(axis=1)                               # [C]
     keep = np.ceil(keep_frac * nnz).astype(np.int64)      # [C], 0 when empty
@@ -186,14 +182,13 @@ def prune_chunks(
         keep_mask, order,
         np.arange(r)[None, :] < keep[:, None], axis=1,
     )
-    r_new = max(min_width, _round_up(max(1, int(keep.max(initial=1))),
-                                     row_align))
+    r_new = lane_width(int(keep.max(initial=1)))
     out_rows = np.full((c, r_new), sentinel, dtype=rows.dtype)
-    out_vals = np.zeros((c, r_new) + vals.shape[2:], dtype=vals.dtype)
+    out_vals = np.zeros((c, vals.shape[1], r_new), dtype=vals.dtype)
     for ci in range(c):
         src = np.flatnonzero(keep_mask[ci])               # ascending row order
         out_rows[ci, : len(src)] = rows[ci, src]
-        out_vals[ci, : len(src)] = vals[ci, src]
+        out_vals[ci, :, : len(src)] = vals[ci][:, src]
     return out_rows, out_vals
 
 

@@ -96,6 +96,83 @@ def test_grouped_bitwise_vs_dense_lookup(rng):
     assert_cross_method_close(got, dense)
 
 
+@pytest.mark.parametrize(
+    "path", ["grouped", "fused", "pregather", "mscm_dense", "int8_grouped"]
+)
+def test_lane_padded_tiles_match_old_layout_oracle(rng, path):
+    """Every reader of the ``[C, B, Rp]`` tiles scores what the einsum
+    oracle scores on the same weights laid out ``[C, R, B]``, to the
+    cross-method contract: contracting over the last axis in place of the
+    middle one changes the order in which the CPU sums the R terms, never
+    the terms (the padding rows add exact zeros). The int8 kernel is, in
+    addition, bitwise the exact kernel on its dequantized tiles."""
+    from repro.core.tree import TreeLayerArrays
+    from repro.quant import dequantize_layer, quantize_layer
+    from repro.quant.kernels import mscm_pallas_grouped_q
+
+    n, d, C, B, A = 7, 300, 5, 8, 23
+    w = random_sparse_csc(d, C * B, 40, rng, sibling_groups=B)
+    ch = ChunkedLayer.from_csc(w, B)
+    assert ch.vals.shape == (C, B, ch.R) and ch.R % 128 == 0
+    x = random_sparse_csr(n, d, 60, rng)
+    xi, xv = map(jnp.asarray, x.to_ell())
+    xd = M.scatter_dense(xi, xv, d)
+    bq = jnp.asarray(rng.integers(0, n, size=A).astype(np.int32))
+    bc = jnp.asarray(rng.integers(0, C, size=A).astype(np.int32))
+    rows, vals = jnp.asarray(ch.rows), jnp.asarray(ch.vals)
+    if path == "int8_grouped":
+        q = quantize_layer(TreeLayerArrays(rows, vals, rows, rows))
+        vals = dequantize_layer(q, d=d).chunk_vals
+        got = mscm_pallas_grouped_q(
+            xi, xv, d, q.chunk_rows, q.chunk_vals, q.chunk_scales, bq, bc,
+            qt=4, interpret=True,
+        )
+        exact = ops.mscm_pallas_grouped(
+            xi, xv, d, rows, vals, bq, bc, qt=4, interpret=True
+        )
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(exact))
+    elif path == "grouped":
+        got = ops.mscm_pallas_grouped(
+            xi, xv, d, rows, vals, bq, bc, qt=4, interpret=True
+        )
+    elif path == "mscm_dense":
+        got = M.mscm_dense_lookup(xd, rows, vals, bq, bc)
+    else:
+        got = ops.mscm_pallas(
+            xd, rows, vals, bq, bc, variant=path, interpret=True
+        )
+    xg = xd[bq[:, None], rows[bc]]                       # [A, R]
+    old = jnp.swapaxes(vals, 1, 2)                       # [C, R, B]
+    want = jnp.einsum("ar,arb->ab", xg, old[bc], precision=M.F32)
+    assert np.count_nonzero(np.asarray(want)) > 0
+    assert_cross_method_close(got, want)
+
+
+def test_grouped_kernel_bitwise_tile_by_tile(rng):
+    """The grouped kernel's grid reads each tile's own chunk: bitwise the
+    same ``[QT, Rp] · [B, Rp]ᵀ`` contraction done tile by tile outside
+    Pallas."""
+    from repro.kernels.mscm_kernel import CONTRACT_R
+
+    xi, xv, _, rows, vals, bq, bc, _ = _mk(
+        rng, n=7, d=300, C=5, B=8, nnz_w=40, nnz_x=60, A=23, ell=True
+    )
+    qt, b = 4, vals.shape[1]
+    bq, bc = jnp.asarray(bq), jnp.asarray(bc)
+    got = ops.mscm_pallas_grouped(
+        xi, xv, 300, rows, vals, bq, bc, qt=qt, interpret=True
+    )
+    tc, ts, order, flat_pos = ops.group_blocks_device(bc, qt, vals.shape[0])
+    tiles = ops.intersect_query_tiles(xi, xv, 300, rows, bq, bc, ts)
+    dot = jax.jit(lambda a, w: jax.lax.dot_general(
+        a, w, CONTRACT_R, precision=M.F32,
+        preferred_element_type=jnp.float32))
+    per_tile = np.stack([np.asarray(dot(tiles[t], vals[int(tc[t])]))
+                         for t in range(tiles.shape[0])])
+    want = per_tile.reshape(-1, b)[np.asarray(flat_pos)[np.argsort(order)]]
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 @pytest.mark.parametrize("mode", ["prod", "logsum"])
 def test_grouped_fused_epilogue(rng, mode):
     """σ⊗parent epilogue fused in-kernel == epilogue applied to raw logits."""

@@ -78,7 +78,7 @@ def test_quantize_dequantize_error_bound(quant_setup):
         err = np.abs(
             np.asarray(deq.chunk_vals) - np.asarray(lay.chunk_vals)
         )
-        bound = np.asarray(q.chunk_scales)[:, None, :] * (0.5 + 1e-5)
+        bound = np.asarray(q.chunk_scales)[:, :, None] * (0.5 + 1e-5)
         assert (err <= bound).all()
         assert np.asarray(q.chunk_vals).dtype == np.int8
         # the ELL mask is never perturbed
@@ -92,12 +92,12 @@ def test_zero_column_dequantizes_to_exact_zero(quant_setup):
     tree, *_ = quant_setup
     lay = tree.layers[-1]
     vals = np.asarray(lay.chunk_vals).copy()
-    vals[:, :, 0] = 0.0  # zero out one column per chunk
+    vals[:, 0, :] = 0.0  # zero out one column per chunk
     q = quantize_layer(lay, vals=vals)
     scales = np.asarray(q.chunk_scales)
     assert (scales[:, 0] == 1.0).all()
     deq = np.asarray(dequantize_layer(q, d=tree.d).chunk_vals)
-    assert (deq[:, :, 0] == 0.0).all()
+    assert (deq[:, 0, :] == 0.0).all()
 
 
 def test_prune_chunks_keeps_heavy_rows_bitwise(quant_setup):
@@ -108,7 +108,7 @@ def test_prune_chunks_keeps_heavy_rows_bitwise(quant_setup):
     keep_frac = 0.5
     new_rows, new_vals = prune_chunks(rows, vals, keep_frac, sentinel=tree.d)
     c, r_new = new_rows.shape
-    assert r_new % 8 == 0 and r_new >= 8
+    assert r_new % 128 == 0 and r_new >= 128
     assert r_new <= rows.shape[1]
     for ci in range(c):
         valid = rows[ci] != tree.d
@@ -117,17 +117,17 @@ def test_prune_chunks_keeps_heavy_rows_bitwise(quant_setup):
         got_valid = new_rows[ci] != tree.d
         assert int(got_valid.sum()) == expect_keep
         # survivors are exactly the top-|.| rows (stable: low index on ties)
-        mag = np.where(valid, np.abs(vals[ci]).max(axis=1), -1.0)
+        mag = np.where(valid, np.abs(vals[ci]).max(axis=0), -1.0)
         order = np.argsort(-mag, kind="stable")[:expect_keep]
         expect_rows = rows[ci][np.sort(order)]          # ascending row order
         np.testing.assert_array_equal(new_rows[ci][:expect_keep], expect_rows)
         # kept weights are bitwise the originals
         np.testing.assert_array_equal(
-            new_vals[ci][:expect_keep], vals[ci][np.sort(order)]
+            new_vals[ci][:, :expect_keep], vals[ci][:, np.sort(order)]
         )
         # padding is sentinel/0
         assert (new_rows[ci][expect_keep:] == tree.d).all()
-        assert (new_vals[ci][expect_keep:] == 0.0).all()
+        assert (new_vals[ci][:, expect_keep:] == 0.0).all()
 
 
 def test_prune_chunks_keep_frac_one_is_lossless(quant_setup):
@@ -140,8 +140,8 @@ def test_prune_chunks_keep_frac_one_is_lossless(quant_setup):
         valid = rows[ci] != tree.d
         np.testing.assert_array_equal(new_rows[ci][: valid.sum()],
                                       rows[ci][valid])
-        np.testing.assert_array_equal(new_vals[ci][: valid.sum()],
-                                      vals[ci][valid])
+        np.testing.assert_array_equal(new_vals[ci][:, : valid.sum()],
+                                      vals[ci][:, valid])
 
 
 def test_prune_chunks_rejects_bad_keep_frac(quant_setup):
@@ -171,7 +171,7 @@ if HAVE_HYPOTHESIS:
     def test_error_bound_property(c, r, b, seed):
         """|dequant - v| <= scale/2 for arbitrary chunk tiles (int8)."""
         rng = np.random.default_rng(seed)
-        vals = (rng.standard_normal((c, r, b)) *
+        vals = (rng.standard_normal((c, b, r)) *
                 10.0 ** rng.integers(-3, 3)).astype(np.float32)
         lay = dataclasses.make_dataclass("L", ["chunk_rows", "chunk_vals"])(
             chunk_rows=np.zeros((c, r), np.int32), chunk_vals=vals,
@@ -179,8 +179,8 @@ if HAVE_HYPOTHESIS:
         q = quantize_layer(lay)
         scales = np.asarray(q.chunk_scales)
         deq = (np.asarray(q.chunk_vals).astype(np.float32)
-               * scales[:, None, :])
-        assert (np.abs(deq - vals) <= scales[:, None, :] * (0.5 + 1e-5)).all()
+               * scales[:, :, None])
+        assert (np.abs(deq - vals) <= scales[:, :, None] * (0.5 + 1e-5)).all()
 else:
     @pytest.mark.skip(reason="hypothesis not installed (pip install -e .[dev])")
     def test_error_bound_property():

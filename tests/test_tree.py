@@ -11,6 +11,8 @@ import numpy as np
 import pytest
 
 from repro.core import METHODS, XMRTree
+from repro.core.mscm import scatter_dense
+from repro.core.tree import level_combined
 from repro.sparse import random_sparse_csr
 from tests.conftest import (
     assert_cross_method_close,
@@ -189,6 +191,45 @@ def test_grouped_program_holds_no_dense_table(small_tree):
     grouped = lowered("mscm_pallas_grouped")
     assert not table.search(grouped)
     assert "scatter" not in grouped
+
+
+def test_tiles_padded_to_lanes(small_tree):
+    """Every level stores its tiles ``[C, B, Rp]`` with Rp a multiple of the
+    128-lane width; the padding rows hold the sentinel ``d`` and zeros."""
+    tree, ws, *_ = small_tree
+    for lay, b in zip(tree.layers, tree.branching):
+        rows, vals = np.asarray(lay.chunk_rows), np.asarray(lay.chunk_vals)
+        c, rp = rows.shape
+        assert vals.shape == (c, b, rp) and rp % 128 == 0
+        pad = rows == tree.d
+        assert pad.any(), "the union widths here lie below 128"
+        # the sentinel rows are a suffix of each chunk's row list
+        assert (np.diff(pad.astype(np.int8), axis=1) >= 0).all()
+        assert (vals.transpose(0, 2, 1)[pad] == 0).all()
+
+
+def test_extract_scores_bitwise_as_full_tree(small_tree):
+    """A sub-tree of sliced ``[C, B, Rp]`` tiles, phantom chunk included,
+    scores its chunks bit for bit as the full tree does."""
+    tree, ws, x, xi, xv = small_tree
+    c0, c1 = 2, 6
+    sub = tree.extract(1, c0, c1)
+    full_lay, sub_lay = tree.layers[1], sub.layers[0]
+    assert sub_lay.chunk_vals.shape == (c1 - c0 + 1,) + full_lay.chunk_vals.shape[1:]
+    assert not np.asarray(sub_lay.chunk_vals[-1]).any()
+    n = xi.shape[0]
+    ids = jnp.asarray(np.random.default_rng(3).integers(c0, c1, (n, 3)),
+                      jnp.int32)
+    scores = jnp.ones((n, 3), jnp.float32)
+    for method in ("mscm_pallas_grouped", "mscm_dense"):
+        x_dense = None
+        if method == "mscm_dense":
+            x_dense = scatter_dense(xi, xv, tree.d)
+        want = level_combined(full_lay, 8, tree.d, xi, xv, x_dense, ids,
+                              scores, method=method, score_mode="prod")
+        got = level_combined(sub_lay, 8, tree.d, xi, xv, x_dense, ids - c0,
+                             scores, method=method, score_mode="prod")
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_nonuniform_branching(rng):
