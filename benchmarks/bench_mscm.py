@@ -19,16 +19,14 @@ import numpy as np
 from benchmarks.common import build_benchmark_tree, csv_line, ell_queries, time_fn
 from repro.data.xmr_data import PAPER_SHAPES, scaled_shape
 
-METHODS = ("vanilla", "mscm_dense", "mscm_searchsorted")
+METHODS = ("vanilla", "mscm_dense")
 
 
 def run(datasets: List[str], branchings=(2, 8, 32), *, max_labels=65_536,
         n_batch=128, n_online=16, beam=10, topk=10, seed=0,
         include_pallas=False) -> List[str]:
     lines: List[str] = []
-    methods = METHODS + (
-        ("mscm_pallas", "mscm_pallas_grouped") if include_pallas else ()
-    )
+    methods = METHODS + (("mscm_pallas_grouped",) if include_pallas else ())
     for ds in datasets:
         shape = PAPER_SHAPES[ds]
         if shape.L > max_labels:
@@ -70,7 +68,7 @@ def grouped_report(ds: str = "eurlex-4k", branching: int = 8, *, qt: int = 8,
                    max_labels: int = 32_768, seed: int = 0) -> List[str]:
     """Device-grouped MXU path: per-level tile accounting + batch timing.
 
-    The grouped kernel's win is structural: the fused kernel walks a grid of
+    The grouped kernel's win is structural: a block-per-step grid would walk
     A blocks (one [1,R]·[B,R]ᵀ contraction each), while the grouped kernel
     packs the same blocks chunk-major into QT-row tiles — per level it runs
     ``tiles ≤ A/QT + C`` matmuls (each chunk wastes at most one ragged

@@ -77,7 +77,7 @@ def run(
     n_queries: int = 48,
     max_labels: int = 4096,
     partitions=(2, 4),
-    methods=("mscm_dense", "mscm_searchsorted", "mscm_pallas_grouped"),
+    methods=("mscm_dense", "mscm_pallas_grouped"),
     beam: int = 10,
     topk: int = 10,
     seed: int = 0,

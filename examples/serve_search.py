@@ -106,8 +106,7 @@ def main() -> None:
     # "auto" is the backend's serving path (the grouped Pallas kernel on a
     # TPU, mscm_dense elsewhere). A non-exact tier forces the quantized
     # kernel, so the per-method panel collapses to the single tier method.
-    methods = (("auto", "mscm_searchsorted", "vanilla")
-               if args.tier == "exact" else ("auto",))
+    methods = ("auto", "vanilla") if args.tier == "exact" else ("auto",)
     for method in methods:
         eng = XMRServingEngine(
             tree,

@@ -10,19 +10,19 @@ The active blocks are given as parallel index vectors
 and the result is the dense [A, B] stack of block values — static shapes,
 no dynamic sparsity anywhere.
 
-Iterator variants (paper §4 items 1-4, TPU-adapted — see DESIGN.md §2):
+The two XLA paths of ``core.tree.METHODS`` (paper §4, TPU-adapted):
 
 * ``mscm_dense_lookup``  — dense-lookup analogue: queries pre-scattered into a
   dense [n, d+1] table; per-block gather at the chunk's ELL rows + one
   [R]·[B,R]ᵀ contraction. One traversal *per chunk*.
-* ``mscm_searchsorted``  — binary-search analogue: vectorized searchsorted of
-  the chunk's row list into the query's sorted nnz list (fixed log₂ depth).
-  No dense table required.
 * ``vanilla_columns``    — the non-MSCM baseline (paper Alg. 4): each of the
   B columns of the block intersects with the query *independently* (per-column
   ELL layout). Same result, B× the traversal.
-* hash-map / marching pointers do not transfer to TPU (no pointer-chasing);
-  ``repro.kernels.ref`` keeps a marching-pointer oracle for tests.
+
+The chip's path, the grouped Pallas kernel, lives in ``repro.kernels`` and
+intersects the ELL queries with the chunk rows on the vector unit. Hash-map /
+marching pointers do not transfer to TPU (no pointer-chasing);
+``repro.kernels.ref`` keeps a marching-pointer oracle for tests.
 
 All functions are jit-friendly and differentiable in ``vals``/``x_val``.
 """
@@ -67,45 +67,6 @@ def mscm_dense_lookup(
     return jnp.einsum("ar,abr->ab", xg, vals[block_c], precision=F32)  # [A, B]
 
 
-def gather_query_rows(
-    x_dense: jax.Array, rows: jax.Array, block_q: jax.Array, block_c: jax.Array
-) -> jax.Array:
-    """The gather half of dense-lookup MSCM, exposed for the pre-gathered
-    Pallas kernel (huge-d path where the query row exceeds VMEM)."""
-    return x_dense[block_q[:, None], rows[block_c]]     # [A, R]
-
-
-def _searchsorted_rows(xi: jax.Array, r: jax.Array) -> jax.Array:
-    """Row-wise searchsorted: for each a, positions of r[a,:] in xi[a,:]."""
-    return jax.vmap(lambda a, v: jnp.searchsorted(a, v, side="left"))(xi, r)
-
-
-def mscm_searchsorted(
-    x_idx: jax.Array,     # int32 [n, Q] sorted, sentinel-padded (== d)
-    x_val: jax.Array,     # f32 [n, Q]
-    rows: jax.Array,      # int32 [C, R]
-    vals: jax.Array,      # f32 [C, B, R]
-    block_q: jax.Array,   # int32 [A]
-    block_c: jax.Array,   # int32 [A]
-    d: int,
-) -> jax.Array:
-    """Binary-search MSCM: intersect chunk rows with query nnz (paper item 2).
-
-    One log₂(Q)-depth vectorized binary search per chunk row — the traversal
-    happens once per *chunk*, not once per column, which is the entire MSCM
-    point.
-    """
-    xi = x_idx[block_q]                    # [A, Q]
-    xv = x_val[block_q]                    # [A, Q]
-    r = rows[block_c]                      # [A, R]
-    q = xi.shape[1]
-    pos = _searchsorted_rows(xi, r)        # [A, R] in [0, Q]
-    pos_c = jnp.minimum(pos, q - 1)
-    hit = (jnp.take_along_axis(xi, pos_c, axis=1) == r) & (r < d)
-    xg = jnp.where(hit, jnp.take_along_axis(xv, pos_c, axis=1), 0.0)
-    return jnp.einsum("ar,abr->ab", xg, vals[block_c], precision=F32)
-
-
 def vanilla_columns(
     x_idx: jax.Array,     # int32 [n, Q]
     x_val: jax.Array,     # f32 [n, Q]
@@ -120,7 +81,7 @@ def vanilla_columns(
 
     Expands each block into its B columns and intersects each column's row
     list with the query separately — B independent traversals per block.
-    Bitwise-identical results to the MSCM variants up to summation order.
+    The same result as the MSCM paths up to summation order.
     """
     a = block_q.shape[0]
     cols = block_c[:, None] * branching + jnp.arange(branching)[None, :]  # [A, B]

@@ -3,9 +3,14 @@
 An :class:`XMRTree` holds one :class:`~repro.core.chunked.ChunkedLayer` per
 tree level (plus the vanilla per-column layout for the baseline method) as
 device arrays. ``infer`` runs the full beam search; the per-level masked
-matmul dispatches to any of the MSCM variants or the Pallas kernels, and all
-of them return the same rankings — the paper's "free of charge" property,
-pinned by tests under the contract stated above ``METHODS``.
+matmul dispatches to one of four methods (``METHODS``: the vanilla oracle,
+dense-lookup MSCM, and the grouped Pallas kernel over f32 or quantized
+tiles), and the exact ones return the same rankings — the paper's "free of
+charge" property, pinned by tests under the contract stated above
+``METHODS``. Every fact about a method lives in this module: the names
+(:func:`check_method`), which one ``"auto"`` serves (:func:`resolve_method`),
+which reads a dense query table (:func:`reads_dense_table`), and which
+exact method stands in for the quantized one (:func:`exact_twin`).
 
 Label layout convention: nodes at level l are numbered so that the children
 of node p are [p*B, (p+1)*B) at level l+1 — chunk id == parent id, which is
@@ -45,25 +50,12 @@ from repro.sparse.csr import CSC
 #                         Correctness oracle; B× the traversal work.
 #   mscm_dense            dense-lookup MSCM (paper §4 item 4): queries
 #                         scattered into a dense [n, d+1] table, XLA gather +
-#                         einsum. Best non-Pallas batch method; needs the
-#                         dense table to fit (d ≲ a few M).
-#   mscm_searchsorted     binary-search MSCM (paper §4 item 2): no dense
-#                         table, log₂(Q)-depth intersections. Best when d is
-#                         huge or memory-tight; slower than dense per block.
-#   mscm_pallas           Pallas fused kernel: one [1,R]·[B,R]ᵀ contraction
-#                         per block, in-kernel VMEM gather, chunk-sorted grid
-#                         so each chunk tile is DMA'd once (paper Alg. 3).
-#                         Interpret mode only: Mosaic refuses its in-kernel
-#                         1-D gather, so on TPU it raises NotImplementedError
-#                         (for d ≤ ~1M; larger d takes the pregather kernel).
-#   mscm_pallas_pregather Pallas pregather kernel: XLA gathers query rows in
-#                         HBM, kernel streams [1,R]·[B,R]ᵀ. The huge-d TPU
-#                         path (enterprise d = 4M).
+#                         einsum. What ``"auto"`` serves off a TPU.
 #   mscm_pallas_grouped   MXU-tiled grouped kernel: blocks packed per chunk
 #                         into QT-row tiles *on device*, one [QT,R]·[B,R]ᵀ
 #                         matmul per tile with the σ⊗parent beam epilogue
-#                         fused in-kernel. The high-throughput batch TPU
-#                         path — amortizes each chunk tile over up to QT
+#                         fused in-kernel. What ``"auto"`` serves on a TPU,
+#                         at any d — amortizes each chunk tile over up to QT
 #                         queries and keeps the whole traversal in one XLA
 #                         program. Query tiles come from intersecting the
 #                         ELL queries with the chunk rows: no dense table.
@@ -78,12 +70,59 @@ from repro.sparse.csr import CSC
 METHODS = (
     "vanilla",
     "mscm_dense",
-    "mscm_searchsorted",
-    "mscm_pallas",
-    "mscm_pallas_pregather",
     "mscm_pallas_grouped",
     "mscm_pallas_grouped_q",
 )
+
+
+def check_method(method: str) -> str:
+    """``method`` itself if :data:`METHODS` holds it; a ``ValueError`` naming
+    them otherwise. Entry points call it before anything is traced."""
+    if method not in METHODS:
+        raise ValueError(f"unknown method {method!r}; choose from {METHODS}")
+    return method
+
+
+def reads_dense_table(method: str) -> bool:
+    """Whether ``method`` scores from the dense ``[n, d+1]`` query table
+    (:func:`repro.core.mscm.scatter_dense`) that its caller builds once per
+    batch. Only the dense-lookup path does."""
+    return method == "mscm_dense"
+
+
+def exact_twin(method: str) -> str:
+    """The method that scores f32 tiles as ``method`` scores its own: the
+    exact grouped kernel for the quantized one (same grouping, same
+    epilogue), ``method`` itself otherwise."""
+    return "mscm_pallas_grouped" if method == "mscm_pallas_grouped_q" else method
+
+
+def resolve_method(method: str, tier: str = "exact") -> str:
+    """The method a server runs for a configured ``method`` and quantization
+    ``tier``.
+
+    ``"auto"`` resolves to the device-grouped MXU-tiled Pallas kernel on a
+    TPU (the paper's batch-mode fast path, fully inside the ``_tree_infer``
+    jit) and to the dense-lookup einsum elsewhere: Pallas interpret mode is
+    for validation, not speed. A compressed tier stores int8/fp8 chunk
+    tiles, which only the quantized grouped kernel reads; naming an exact
+    method beside one is a contradiction, refused rather than overridden.
+    """
+    if tier != "exact":
+        if method not in ("auto", "mscm_pallas_grouped_q"):
+            raise ValueError(
+                f"quant tier {tier!r} serves via "
+                f"method='mscm_pallas_grouped_q'; got explicit "
+                f"method={method!r}"
+            )
+        return "mscm_pallas_grouped_q"
+    if method == "auto":
+        return (
+            "mscm_pallas_grouped"
+            if jax.default_backend() == "tpu"
+            else "mscm_dense"
+        )
+    return check_method(method)
 
 
 @dataclasses.dataclass
@@ -272,9 +311,10 @@ class XMRTree:
         """Beam-search inference. Returns (scores [n, k], labels [n, k]).
 
         ``method`` picks the masked-matmul backend (see the table above the
-        ``METHODS`` tuple); ``qt`` is the query-tile height of the grouped
-        Pallas kernel (ignored by other methods). All methods return the
-        same rankings (see the contract above ``METHODS``).
+        ``METHODS`` tuple; another name raises ``ValueError``); ``qt`` is
+        the query-tile height of the grouped Pallas kernel (ignored by other
+        methods). All methods return the same rankings (see the contract
+        above ``METHODS``).
 
         ``init_parent_ids``/``init_scores`` (int32/f32 ``[n, b]``) start the
         search from an externally-computed beam instead of the root — the
@@ -296,55 +336,11 @@ class XMRTree:
             init_scores,
             beam=beam,
             topk=topk,
-            method=method,
+            method=check_method(method),
             score_mode=score_mode,
             qt=qt,
             clamp_chunks=clamp_chunks,
         )
-
-
-def _masked_matmul(
-    layer: TreeLayerArrays,
-    x_idx: jax.Array,
-    x_val: jax.Array,
-    x_dense: jax.Array | None,
-    block_q: jax.Array,
-    block_c: jax.Array,
-    branching: int,
-    d: int,
-    method: str,
-) -> jax.Array:
-    """Dispatch one level's masked product A = M ⊙ (X W) (paper eq. 6)."""
-    if method == "vanilla":
-        return mscm_lib.vanilla_columns(
-            x_idx, x_val, layer.col_rows, layer.col_vals, block_q, block_c, branching, d
-        )
-    if method == "mscm_dense":
-        return mscm_lib.mscm_dense_lookup(
-            x_dense, layer.chunk_rows, layer.chunk_vals, block_q, block_c
-        )
-    if method == "mscm_searchsorted":
-        return mscm_lib.mscm_searchsorted(
-            x_idx, x_val, layer.chunk_rows, layer.chunk_vals, block_q, block_c, d
-        )
-    if method in ("mscm_pallas", "mscm_pallas_pregather"):
-        from repro.kernels import ops  # local import: kernels are optional
-
-        variant = "pregather" if method.endswith("pregather") else "auto"
-        return ops.mscm_pallas(
-            x_dense, layer.chunk_rows, layer.chunk_vals, block_q, block_c, variant=variant
-        )
-    if method in ("mscm_pallas_grouped", "mscm_pallas_grouped_q"):
-        # Dispatched directly in _tree_infer: the grouped kernels fuse the
-        # σ⊗parent epilogue with the beam step, which needs the parent
-        # scores this function never sees. Raw logits are available via
-        # ops.mscm_grouped_level / repro.quant.kernels.mscm_grouped_q_level
-        # with mode="none".
-        raise ValueError(
-            f"{method} is dispatched inside _tree_infer; use the "
-            "mscm_grouped(_q)_level wrappers for a bare matmul"
-        )
-    raise ValueError(f"unknown method {method!r}; choose from {METHODS}")
 
 
 def level_combined(
@@ -408,10 +404,21 @@ def level_combined(
             qt=qt,
             mode=score_mode,
         ).reshape(n, b_cur, branching)
-    logits = _masked_matmul(
-        layer, x_idx, x_val, x_dense, block_q, block_c, branching, d, method
-    ).reshape(n, b_cur, branching)
-    return combine_scores(parent_scores, logits, score_mode)
+    # The masked product A = M ⊙ (X W) (paper eq. 6), then the epilogue.
+    if method == "vanilla":
+        logits = mscm_lib.vanilla_columns(
+            x_idx, x_val, layer.col_rows, layer.col_vals, block_q, block_c,
+            branching, d,
+        )
+    elif method == "mscm_dense":
+        logits = mscm_lib.mscm_dense_lookup(
+            x_dense, layer.chunk_rows, layer.chunk_vals, block_q, block_c
+        )
+    else:
+        raise ValueError(f"unknown method {method!r}; choose from {METHODS}")
+    return combine_scores(
+        parent_scores, logits.reshape(n, b_cur, branching), score_mode
+    )
 
 
 def owned_level_combined(
@@ -483,10 +490,10 @@ def _tree_infer(
     clamp_chunks: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     n = x_idx.shape[0]
-    needs_dense = method in (
-        "mscm_dense", "mscm_pallas", "mscm_pallas_pregather",
+    x_dense = (
+        mscm_lib.scatter_dense(x_idx, x_val, d)
+        if reads_dense_table(method) else None
     )
-    x_dense = mscm_lib.scatter_dense(x_idx, x_val, d) if needs_dense else None
 
     if init_parent_ids is not None:
         # Continuation from an external beam (scatter–gather partitions).

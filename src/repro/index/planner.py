@@ -14,7 +14,7 @@ Query path (``sync="level"``, **bitwise-exact**):
    (score desc, id asc) :func:`~repro.core.beam.beam_select`. Steps 2–3
    repeat per partitioned level; the final level's select *is* the global
    top-k — results are **bitwise-identical** to the unpartitioned tree for
-   every MSCM method (pinned by tests and a structural benchmark flag).
+   every exact method (pinned by tests and a structural benchmark flag).
 
 ``sync="pipelined"`` keeps the same bitwise contract while taking the
 per-level exchange off the partitions' critical path. In ``"level"`` mode a
@@ -86,7 +86,12 @@ import numpy as np
 
 from repro.core import mscm as mscm_lib
 from repro.core.beam import NEG_INF, beam_select, topk_canonical
-from repro.core.tree import owned_level_combined
+from repro.core.tree import (
+    check_method,
+    exact_twin,
+    owned_level_combined,
+    reads_dense_table,
+)
 from repro.index.cache import HotBeamCache
 from repro.index.partition import PartitionedIndex
 from repro.index.placement import Placement
@@ -389,6 +394,7 @@ class ScatterGatherPlanner:
         cache_entries: int = 0,
         transport: Optional[BeamTransport] = None,
     ) -> None:
+        check_method(method)
         if sync not in SYNC_MODES:
             raise ValueError(f"sync={sync!r}; choose from {SYNC_MODES}")
         self.transport = None
@@ -418,17 +424,10 @@ class ScatterGatherPlanner:
                 p.device_put(sh)
                 for p, sh in zip(index.parts, placement.array_shardings)
             ]
-        self._needs_dense = method in (
-            "mscm_dense", "mscm_pallas", "mscm_pallas_pregather",
-        )
+        self._needs_dense = reads_dense_table(method)
         # The router head is always exact f32 (only the partitions are
-        # quantized — repro.quant.quantize_index), so a quantized method
-        # routes through its exact grouped twin: same grouping, same
-        # epilogue, f32 tiles.
-        self._router_method = (
-            "mscm_pallas_grouped"
-            if method == "mscm_pallas_grouped_q" else method
-        )
+        # quantized — repro.quant.quantize_index).
+        self._router_method = exact_twin(method)
         self.cache: Optional[HotBeamCache] = None
         if cache_entries:
             if sync == "final":

@@ -1,50 +1,37 @@
-"""Pallas TPU kernels for Masked Sparse Chunk Multiplication.
+"""Pallas TPU kernel for Masked Sparse Chunk Multiplication.
 
-Three kernels, all driven by a scalar-prefetched active-block list that the
-caller sorts by chunk id (paper §4, final optimization: evaluate blocks in
-chunk order so each chunk enters fast memory once). On TPU the sort is not
-merely a cache *hint*: Pallas's pipelining skips re-copying an input block
-whose ``index_map`` output is unchanged between consecutive grid steps, so a
-chunk-sorted grid makes the chunk tile *structurally* VMEM-resident across
-all the queries that hit it.
-
-Kernels
--------
-``fused``      dense-lookup analogue for small/medium d: the query's dense
-               row lives in VMEM, the gather at the chunk's ELL rows happens
-               in-kernel, followed by a [1,R]·[B,R]ᵀ contraction.
-``pregather``  huge-d path (e.g. enterprise d = 4M, a dense row would blow
-               VMEM): XLA gathers x at chunk rows in HBM, the kernel streams
-               the pre-gathered [A, R] rows against chunk tiles.
-``grouped``    MXU-tiled batch path: blocks grouped per chunk into query
-               tiles of QT rows → one [QT,R]·[B,R]ᵀ matmul per tile, with the
-               beam-search epilogue (σ(logit) ⊗ parent score, paper eq. 5)
-               optionally fused into the kernel body so logits never
-               round-trip through HBM between matmul and beam step. Grouping
-               is device-side (:func:`repro.kernels.ops.group_blocks_device`)
-               so the whole traversal compiles as one XLA program; the
-               host-side :func:`group_blocks_by_chunk` remains as the
-               reference grouping used by tests/benchmark accounting.
+The grouped kernel is the chip's path (``method="mscm_pallas_grouped"``):
+active blocks are grouped per chunk into query tiles of QT rows, and each
+tile is one [QT,R]·[B,R]ᵀ matmul on the MXU, with the beam-search epilogue
+(σ(logit) ⊗ parent score, paper eq. 5) optionally fused into the kernel body
+so logits never round-trip through HBM between matmul and beam step. The
+tile list is driven by a scalar-prefetched chunk id per tile, chunk-major
+(paper §4, final optimization: evaluate blocks in chunk order so each chunk
+enters fast memory once). On TPU the order is not merely a cache *hint*:
+Pallas's pipelining skips re-copying an input block whose ``index_map``
+output is unchanged between consecutive grid steps, so a chunk-sorted grid
+makes the chunk tile *structurally* VMEM-resident across all the queries
+that hit it. Grouping is device-side
+(:func:`repro.kernels.ops.group_blocks_device`) so the whole traversal
+compiles as one XLA program; the host-side :func:`group_blocks_by_chunk`
+remains as the reference grouping used by tests/benchmark accounting.
 
 Alignment notes (TPU target; interpret mode ignores these):
 * Mosaic requires the last two dims of every block to be multiples of
   (8, 128) or equal to the array's own; per-row operands therefore carry a
-  unit middle dim (``[A, 1, R]``, ``[T, QT, 1]``) instead of a ``(1, R)``
-  block over ``[A, R]``.
+  unit middle dim (``[T, QT, 1]``) instead of a ``(1, QT)`` block over
+  ``[T, QT]``.
 * Chunk tiles are stored ``[C, B, R]`` with R padded to a multiple of 128
-  by ``ChunkedLayer.from_csc``, and every kernel contracts a query row
-  ``[., R]`` with a tile ``[B, R]`` over their last axes. The reason is
-  the layout XLA gives the stored array: a ``[C, R, B<128]`` array (or a
+  by ``ChunkedLayer.from_csc``, and the kernel contracts a query tile
+  ``[QT, R]`` with a chunk tile ``[B, R]`` over their last axes. The reason
+  is the layout XLA gives the stored array: a ``[C, R, B<128]`` array (or a
   ``[C, B, R]`` one with R not a multiple of 128) is laid out with C on the
   lanes, and XLA copies it into the kernel's row-major (8, 128) tiling, B
   padded to 128 lanes, on every call: 4x the tile bytes written, and the
   largest op of every dispatch. ``[C, B, R % 128 == 0]`` is already the
   kernel's layout, so the tiles are read where they lie.
-* B < 128 underutilizes the MXU's output lanes; the grouped kernel's tiles
-  put QT query rows on sublanes to compensate.
-* The fused kernel's in-kernel 1-D gather (``jnp.take``) does not lower on
-  Mosaic ("Only 2D gather is supported"): it runs in interpret mode only,
-  and ``ops.mscm_pallas`` refuses it on a TPU backend.
+* B < 128 underutilizes the MXU's output lanes; the tiles put QT query rows
+  on sublanes to compensate.
 """
 
 from __future__ import annotations
@@ -66,98 +53,7 @@ CONTRACT_R = (((1,), (1,)), ((), ()))
 
 
 # ---------------------------------------------------------------------------
-# fused: in-kernel gather from a VMEM-resident dense query row
-# ---------------------------------------------------------------------------
-
-def _fused_body(bq_ref, bc_ref, x_ref, rows_ref, vals_ref, out_ref):
-    del bq_ref, bc_ref  # consumed by the index maps
-    r = rows_ref[0, :]                                   # [R] int32
-    xg = jnp.take(x_ref[0, :], r, mode="clip")           # [R] VMEM gather
-    acc = jax.lax.dot_general(
-        xg[None, :], vals_ref[0],
-        dimension_numbers=CONTRACT_R,
-        precision=F32,
-        preferred_element_type=jnp.float32,
-    )                                                    # [1, B]
-    out_ref[0, :] = acc[0]
-
-
-def mscm_fused(
-    x_dense: jax.Array,   # f32 [n, Dp]  (Dp >= d+1; sentinel slot is zero)
-    rows: jax.Array,      # int32 [C, R]
-    vals: jax.Array,      # f32 [C, B, R]
-    block_q: jax.Array,   # int32 [A]  sorted by block_c for chunk reuse
-    block_c: jax.Array,   # int32 [A]
-    *,
-    interpret: bool = False,
-) -> jax.Array:
-    a = block_q.shape[0]
-    _, dp = x_dense.shape
-    c, b, r = vals.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(a,),
-        in_specs=[
-            pl.BlockSpec((1, dp), lambda i, bq, bc: (bq[i], 0)),
-            pl.BlockSpec((1, r), lambda i, bq, bc: (bc[i], 0)),
-            pl.BlockSpec((1, b, r), lambda i, bq, bc: (bc[i], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, b), lambda i, bq, bc: (i, 0)),
-    )
-    return pl.pallas_call(
-        _fused_body,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((a, b), jnp.float32),
-        interpret=interpret,
-    )(block_q, block_c, x_dense, rows, vals)
-
-
-# ---------------------------------------------------------------------------
-# pregather: XLA does the HBM gather, kernel streams [1,R] x [B,R]^T
-# ---------------------------------------------------------------------------
-
-def _pregather_body(bc_ref, xg_ref, vals_ref, out_ref):
-    del bc_ref
-    acc = jax.lax.dot_general(
-        xg_ref[0], vals_ref[0],
-        dimension_numbers=CONTRACT_R,
-        precision=F32,
-        preferred_element_type=jnp.float32,
-    )                                                    # [1, B]
-    out_ref[0] = acc
-
-
-def mscm_pregather(
-    xg: jax.Array,        # f32 [A, R]  pre-gathered query values
-    vals: jax.Array,      # f32 [C, B, R]
-    block_c: jax.Array,   # int32 [A] sorted
-    *,
-    interpret: bool = False,
-) -> jax.Array:
-    a, r = xg.shape
-    c, b, _ = vals.shape
-    # Rows ride as [A, 1, R] / [A, 1, B] so every block's last two dims equal
-    # the array's (a (1, R) block over [A, R] breaks Mosaic's (8, 128) rule).
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(a,),
-        in_specs=[
-            pl.BlockSpec((1, 1, r), lambda i, bc: (i, 0, 0)),
-            pl.BlockSpec((1, b, r), lambda i, bc: (bc[i], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, b), lambda i, bc: (i, 0, 0)),
-    )
-    out = pl.pallas_call(
-        _pregather_body,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((a, 1, b), jnp.float32),
-        interpret=interpret,
-    )(block_c, xg[:, None, :], vals)
-    return out.reshape(a, b)
-
-
-# ---------------------------------------------------------------------------
-# grouped: host-grouped chunk-major query tiles -> MXU matmuls
+# grouped: chunk-major query tiles -> MXU matmuls
 # ---------------------------------------------------------------------------
 
 def group_blocks_by_chunk(

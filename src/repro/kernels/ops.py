@@ -1,4 +1,4 @@
-"""Jitted wrappers around the MSCM Pallas kernels.
+"""Jitted wrappers around the grouped MSCM Pallas kernel.
 
 ``interpret=None`` follows the backend: on a TPU the kernels are compiled by
 Mosaic; on any other backend (the CPU test runs) the kernel body executes in
@@ -20,16 +20,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.mscm import gather_query_rows
-from repro.kernels.mscm_kernel import (
-    mscm_fused,
-    mscm_grouped,
-    mscm_pregather,
-)
-
-# A dense f32 query row above this many elements does not fit comfortably in
-# VMEM alongside the chunk tile; fall back to the pre-gathered kernel.
-VMEM_ROW_LIMIT = 1 << 20
+from repro.kernels.mscm_kernel import mscm_grouped
 
 # Query-tile height of the grouped kernel: rows per [QT, R] x [B, R]^T matmul.
 DEFAULT_QT = 8
@@ -39,22 +30,6 @@ def _auto_interpret(interpret: Optional[bool]) -> bool:
     if interpret is None:
         return jax.default_backend() != "tpu"
     return bool(interpret)
-
-
-def sort_blocks_by_chunk(block_q: jax.Array, block_c: jax.Array):
-    """In-jit chunk-major ordering (paper Alg. 3 line 6-8) + inverse perm."""
-    order = jnp.argsort(block_c, stable=True)
-    return block_q[order], block_c[order], order
-
-
-def unsort(out_sorted: jax.Array, order: jax.Array) -> jax.Array:
-    """Undo a permutation by *gathering* through its inverse.
-
-    ``argsort(order)`` is the inverse permutation; a gather through it is
-    TPU-friendly, unlike the scatter ``zeros.at[order].set(out)`` (scatters
-    serialize on TPU and block fusion with the consumer).
-    """
-    return out_sorted[jnp.argsort(order)]
 
 
 # ---------------------------------------------------------------------------
@@ -80,7 +55,8 @@ def group_blocks_device(
     """In-jit, scatter-free grouping of active blocks into per-chunk tiles.
 
     The static tile count is :func:`grouped_tile_bound`; every construction
-    step is a sort, searchsorted, or gather (no scatters — see ``unsort``).
+    step is a sort, searchsorted, or gather: no scatters, which serialize
+    on TPU and block fusion with the consumer.
 
     Returns
       tile_chunk [T]      chunk id per tile (padding tiles repeat the last
@@ -194,49 +170,8 @@ def mscm_grouped_level(
 
 
 # ---------------------------------------------------------------------------
-# Jitted entry points
+# Jitted entry point
 # ---------------------------------------------------------------------------
-
-@functools.partial(
-    jax.jit, static_argnames=("variant", "sort", "interpret")
-)
-def mscm_pallas(
-    x_dense: jax.Array,   # f32 [n, Dp]
-    rows: jax.Array,      # int32 [C, R]
-    vals: jax.Array,      # f32 [C, B, R]
-    block_q: jax.Array,   # int32 [A]
-    block_c: jax.Array,   # int32 [A]
-    *,
-    variant: str = "auto",
-    sort: bool = True,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    """Masked chunk multiplication via Pallas. Returns f32 [A, B]."""
-    interp = _auto_interpret(interpret)
-    if variant == "auto":
-        variant = "fused" if x_dense.shape[1] <= VMEM_ROW_LIMIT else "pregather"
-    if variant == "fused" and not interp:
-        # Mosaic refuses the kernel's in-kernel 1-D gather over the VMEM
-        # query row ("Only 2D gather is supported"); never swap in another
-        # kernel behind the caller's back.
-        raise NotImplementedError(
-            "the fused MSCM kernel does not lower on TPU: its in-kernel "
-            "1-D jnp.take gather is refused by Mosaic; use "
-            "method='mscm_pallas_pregather' or 'mscm_pallas_grouped'"
-        )
-    if sort:
-        bq, bc, order = sort_blocks_by_chunk(block_q, block_c)
-    else:
-        bq, bc, order = block_q, block_c, None
-    if variant == "fused":
-        out = mscm_fused(x_dense, rows, vals, bq, bc, interpret=interp)
-    elif variant == "pregather":
-        xg = gather_query_rows(x_dense, rows, bq, bc)
-        out = mscm_pregather(xg, vals, bc, interpret=interp)
-    else:
-        raise ValueError(f"unknown variant {variant}")
-    return unsort(out, order) if order is not None else out
-
 
 @functools.partial(
     jax.jit, static_argnames=("d", "qt", "mode", "interpret")

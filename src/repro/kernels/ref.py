@@ -2,7 +2,7 @@
 
 ``mscm_ref`` is the dense-algebra ground truth: reconstruct W from the chunk
 tiles, evaluate the full product X·W, and read out the masked blocks. Every
-MSCM variant (JAX and Pallas) must match it.
+MSCM path (the XLA einsums and the grouped Pallas kernel) must match it.
 
 ``block_ref_marching`` is a numpy marching-pointer implementation of the
 paper's Algorithm 2 (the one iterator with no TPU analogue) — kept as an

@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.tree import XMRTree
+from repro.core.tree import XMRTree, resolve_method
 from repro.serving import spans
 from repro.serving.config import (
     AdmissionConfig,
@@ -69,23 +69,6 @@ __all__ = [
 ]
 
 
-def resolve_method(method: str) -> str:
-    """Resolve ``"auto"`` to the best batch method for the active backend.
-
-    On TPU that is the device-grouped MXU-tiled Pallas kernel (the paper's
-    batch-mode fast path, fully inside the ``_tree_infer`` jit); elsewhere
-    the dense-lookup einsum path — Pallas interpret mode is for validation,
-    not speed.
-    """
-    if method != "auto":
-        return method
-    return (
-        "mscm_pallas_grouped"
-        if jax.default_backend() == "tpu"
-        else "mscm_dense"
-    )
-
-
 def _bucket(n: int, max_batch: int) -> int:
     b = 1
     while b < n and b < max_batch:
@@ -97,20 +80,8 @@ class XMRServingEngine:
     def __init__(self, tree: XMRTree, config: ServeConfig | None = None,
                  label_perm: Optional[np.ndarray] = None):
         self.config = config or ServeConfig()
-        self.method = resolve_method(self.config.method)
         qc = self.config.quant
-        if qc.tier != "exact":
-            # Compressed tiers store int8/fp8 chunk tiles + scale rows; the
-            # quantized grouped kernel is the only method that can read
-            # them. "auto" resolves there; an explicit exact method is a
-            # config contradiction, not something to silently override.
-            if self.config.method not in ("auto", "mscm_pallas_grouped_q"):
-                raise ValueError(
-                    f"quant tier {qc.tier!r} serves via "
-                    f"method='mscm_pallas_grouped_q'; got explicit "
-                    f"method={self.config.method!r}"
-                )
-            self.method = "mscm_pallas_grouped_q"
+        self.method = resolve_method(self.config.method, qc.tier)
         # Adaptive beam-tier ladder (tier 0 = the configured full beam; a
         # 1-tuple unless slo.target_p99_ms is set). Degraded tiers must
         # reach the same result width as the full beam or QueryResult

@@ -47,8 +47,6 @@ import numpy as np
 
 from repro.serving.fleet.rpc import recv_frame, send_frame
 
-_NEEDS_DENSE = ("mscm_dense", "mscm_pallas", "mscm_pallas_pregather")
-
 
 class PartitionRunner:
     """One partition's half of the pipelined beam-exchange protocol."""
@@ -60,7 +58,11 @@ class PartitionRunner:
     ) -> None:
         import jax.numpy as jnp
 
-        from repro.core.tree import TreeLayerArrays, XMRTree
+        from repro.core.tree import (
+            TreeLayerArrays,
+            XMRTree,
+            reads_dense_table,
+        )
 
         self.pid = int(header["pid"])
         self.level = int(header["level"])          # split level li0
@@ -70,6 +72,7 @@ class PartitionRunner:
         self.beam = int(header["beam"])
         self.topk = int(header["topk"])
         self.method = str(header["method"])
+        self._needs_dense = reads_dense_table(self.method)
         self.score_mode = str(header["score_mode"])
         self.qt = int(header["qt"])
         self.tier = str(header.get("tier", "exact"))
@@ -178,7 +181,7 @@ class PartitionRunner:
         self._xv = jnp.asarray(xv)
         self._xd = (
             _scatter_dense(self._xi, self._xv, self.part.d)
-            if self.method in _NEEDS_DENSE else None
+            if self._needs_dense else None
         )
         ids = jnp.asarray(parent_ids)
         sc = jnp.asarray(scores)

@@ -1,9 +1,10 @@
-"""Pallas MSCM kernel validation (interpret mode) against the jnp oracle.
+"""Grouped Pallas MSCM kernel validation (interpret mode) against the oracles.
 
-Sweeps shapes/dtypes per the assignment; every kernel variant must match
-``ref.mscm_ref`` allclose. TPU is the target; interpret=True executes the
-kernel bodies on CPU. The hypothesis property sweep is skipped when
-hypothesis is not installed; everything else runs everywhere.
+Sweeps shapes, query-tile heights and block orders; the grouped kernel must
+match ``ref.mscm_ref`` allclose and the dense-lookup einsum to a few ulp.
+TPU is the target; interpret=True executes the kernel body on CPU. The
+hypothesis property sweep is skipped when hypothesis is not installed;
+everything else runs everywhere.
 """
 
 import jax
@@ -42,43 +43,54 @@ def _mk(rng, n, d, C, B, nnz_w, nnz_x, A, *, ell=False):
     return (xi, xv) + out if ell else out
 
 
-@pytest.mark.parametrize("variant", ["fused", "pregather"])
-def test_pallas_variants_basic(rng, variant):
-    xd, rows, vals, bq, bc, want = _mk(rng, n=5, d=96, C=4, B=8, nnz_w=8, nnz_x=12, A=10)
-    got = ops.mscm_pallas(
-        xd, rows, vals, jnp.asarray(bq), jnp.asarray(bc), variant=variant, interpret=True
-    )
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
-
-
-@pytest.mark.parametrize("sort", [True, False])
-def test_pallas_sort_invariance(rng, sort):
-    """Chunk-sorted evaluation (paper's final §4 optimization) is a pure
-    schedule change — results are identical in any block order."""
-    xd, rows, vals, bq, bc, want = _mk(rng, n=4, d=64, C=6, B=4, nnz_w=6, nnz_x=9, A=12)
-    got = ops.mscm_pallas(
-        xd, rows, vals, jnp.asarray(bq), jnp.asarray(bc), sort=sort, interpret=True
-    )
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
-
-
-def test_pallas_duplicate_chunks_revisit(rng):
-    """Many queries hitting the same chunk (the revisit fast path)."""
-    xd, rows, vals, _, _, _ = _mk(rng, n=8, d=80, C=3, B=8, nnz_w=8, nnz_x=10, A=1)
-    bq = np.arange(8, dtype=np.int32)
-    bc = np.zeros(8, dtype=np.int32)  # all blocks -> chunk 0
-    want = np.asarray(ref_lib.mscm_ref(xd, rows, vals, jnp.asarray(bq), jnp.asarray(bc)))
-    got = ops.mscm_pallas(xd, rows, vals, jnp.asarray(bq), jnp.asarray(bc), interpret=True)
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
-
-
-@pytest.mark.parametrize("qt", [2, 4, 8])
+@pytest.mark.parametrize("qt", [1, 2, 4, 8, 16])
 def test_grouped_kernel(rng, qt):
     xi, xv, _, rows, vals, bq, bc, want = _mk(
         rng, n=7, d=72, C=5, B=8, nnz_w=7, nnz_x=11, A=17, ell=True
     )
     got = ops.mscm_pallas_grouped(
         xi, xv, 72, rows, vals, bq, bc, qt=qt, interpret=True
+    )
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("order", ["identity", "reversed", "shuffled"])
+def test_grouped_block_order_invariance(rng, order):
+    """Chunk-major grouping (paper's final §4 optimization) is a pure
+    schedule change: any order of the block list scores each block as the
+    oracle does, and returns the blocks in the order they were given."""
+    xi, xv, xd, rows, vals, bq, bc, _ = _mk(
+        rng, n=6, d=64, C=6, B=4, nnz_w=6, nnz_x=9, A=19, ell=True
+    )
+    perm = {
+        "identity": np.arange(19),
+        "reversed": np.arange(19)[::-1],
+        "shuffled": rng.permutation(19),
+    }[order]
+    bq, bc = jnp.asarray(bq[perm]), jnp.asarray(bc[perm])
+    want = np.asarray(ref_lib.mscm_ref(xd, rows, vals, bq, bc))
+    got = ops.mscm_pallas_grouped(
+        xi, xv, 64, rows, vals, bq, bc, qt=4, interpret=True
+    )
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("qt", [4, 8])
+def test_grouped_one_chunk_revisit(rng, qt):
+    """Every block on one chunk: the tiles fill to QT rows (a ragged last
+    one at qt=8) and the grid revisits the same chunk tile at every step."""
+    xi, xv, xd, rows, vals, _, _, _ = _mk(
+        rng, n=12, d=80, C=3, B=8, nnz_w=8, nnz_x=10, A=1, ell=True
+    )
+    bq = jnp.arange(12, dtype=jnp.int32)
+    bc = jnp.full((12,), 1, jnp.int32)
+    tc, ts, _, _ = ops.group_blocks_device(bc, qt, 3)
+    real = np.asarray(ts)[:, 0] >= 0
+    assert real.sum() == -(-12 // qt) and (np.asarray(tc) == 1).all()
+    assert (np.asarray(ts)[real] >= 0).sum() == 12
+    want = np.asarray(ref_lib.mscm_ref(xd, rows, vals, bq, bc))
+    got = ops.mscm_pallas_grouped(
+        xi, xv, 80, rows, vals, bq, bc, qt=qt, interpret=True
     )
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
 
@@ -96,9 +108,7 @@ def test_grouped_bitwise_vs_dense_lookup(rng):
     assert_cross_method_close(got, dense)
 
 
-@pytest.mark.parametrize(
-    "path", ["grouped", "fused", "pregather", "mscm_dense", "int8_grouped"]
-)
+@pytest.mark.parametrize("path", ["grouped", "mscm_dense", "int8_grouped"])
 def test_lane_padded_tiles_match_old_layout_oracle(rng, path):
     """Every reader of the ``[C, B, Rp]`` tiles scores what the einsum
     oracle scores on the same weights laid out ``[C, R, B]``, to the
@@ -135,12 +145,8 @@ def test_lane_padded_tiles_match_old_layout_oracle(rng, path):
         got = ops.mscm_pallas_grouped(
             xi, xv, d, rows, vals, bq, bc, qt=4, interpret=True
         )
-    elif path == "mscm_dense":
-        got = M.mscm_dense_lookup(xd, rows, vals, bq, bc)
     else:
-        got = ops.mscm_pallas(
-            xd, rows, vals, bq, bc, variant=path, interpret=True
-        )
+        got = M.mscm_dense_lookup(xd, rows, vals, bq, bc)
     xg = xd[bq[:, None], rows[bc]]                       # [A, R]
     old = jnp.swapaxes(vals, 1, 2)                       # [C, R, B]
     want = jnp.einsum("ar,arb->ab", xg, old[bc], precision=M.F32)
@@ -300,15 +306,6 @@ def test_intersect_query_tiles_bitwise_vs_dense_gather(rng, qt, width, dup):
         assert np.float32(v[p] + v[other]) in np.asarray(got)
 
 
-def test_unsort_is_gather_inverse(rng):
-    """unsort == indexing through the inverse permutation (no scatter)."""
-    a = 17
-    order = jnp.asarray(rng.permutation(a).astype(np.int32))
-    x = jnp.asarray(rng.random((a, 4)).astype(np.float32))
-    got = ops.unsort(x[order], order)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(x))
-
-
 def test_force_interpret_env(monkeypatch):
     """Interpret mode follows the backend and nothing else: compiled on a
     TPU, interpreted elsewhere; an explicit argument always wins."""
@@ -321,16 +318,6 @@ def test_force_interpret_env(monkeypatch):
     assert ops._auto_interpret(False) is False
 
 
-def test_fused_refused_when_compiled(rng):
-    """The fused kernel's in-kernel 1-D gather does not lower on Mosaic, so a
-    compiled (non-interpret) fused call raises rather than silently running
-    another kernel."""
-    xd, rows, vals, bq, bc, _ = _mk(rng, n=4, d=64, C=3, B=8, nnz_w=6, nnz_x=8, A=8)
-    with pytest.raises(NotImplementedError, match="gather"):
-        ops.mscm_pallas(xd, rows, vals, jnp.asarray(bq), jnp.asarray(bc),
-                        variant="fused", interpret=False)
-
-
 if HAS_HYPOTHESIS:
     @settings(max_examples=20, deadline=None)
     @given(
@@ -341,35 +328,20 @@ if HAS_HYPOTHESIS:
         nnz_w=st.integers(1, 12),
         nnz_x=st.integers(1, 16),
         a=st.integers(1, 16),
-        variant=st.sampled_from(["fused", "pregather"]),
+        qt=st.sampled_from([1, 2, 8]),
         seed=st.integers(0, 2**31 - 1),
     )
-    def test_pallas_property_sweep(n, d, c, b, nnz_w, nnz_x, a, variant, seed):
+    def test_grouped_property_sweep(n, d, c, b, nnz_w, nnz_x, a, qt, seed):
         rng = np.random.default_rng(seed)
-        xd, rows, vals, bq, bc, want = _mk(
-            rng, n=n, d=d, C=c, B=b, nnz_w=min(nnz_w, d), nnz_x=min(nnz_x, d), A=a
+        xi, xv, _, rows, vals, bq, bc, want = _mk(
+            rng, n=n, d=d, C=c, B=b, nnz_w=min(nnz_w, d),
+            nnz_x=min(nnz_x, d), A=a, ell=True,
         )
-        got = ops.mscm_pallas(
-            xd, rows, vals, jnp.asarray(bq), jnp.asarray(bc), variant=variant, interpret=True
+        got = ops.mscm_pallas_grouped(
+            xi, xv, d, rows, vals, bq, bc, qt=qt, interpret=True
         )
         np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
 else:
     @pytest.mark.skip(reason="hypothesis not installed (pip install -e .[dev])")
-    def test_pallas_property_sweep():
+    def test_grouped_property_sweep():
         pass
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_pallas_dtype_sweep(rng, dtype):
-    """bf16 weights path (serving quantization) stays within bf16 tolerance."""
-    xd, rows, vals, bq, bc, _ = _mk(rng, n=4, d=64, C=3, B=8, nnz_w=6, nnz_x=8, A=8)
-    vals16 = vals.astype(dtype)
-    xd16 = xd.astype(dtype)
-    want = np.asarray(
-        ref_lib.mscm_ref(xd16.astype(jnp.float32), rows, vals16.astype(jnp.float32),
-                         jnp.asarray(bq), jnp.asarray(bc))
-    )
-    got = ops.mscm_pallas(xd16, rows, vals16, jnp.asarray(bq), jnp.asarray(bc),
-                          interpret=True)
-    tol = 1e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=tol, atol=tol)

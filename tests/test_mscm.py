@@ -1,7 +1,8 @@
-"""MSCM variant correctness: every iterator == dense oracle == each other.
+"""MSCM path correctness: every path == dense oracle == each other.
 
 This pins the paper's headline claim (§4): MSCM is *exact* — it returns the
-same masked product as the vanilla per-column baseline, for every iterator.
+same masked product as the vanilla per-column baseline, whether through the
+dense-lookup einsum or the grouped Pallas kernel (interpret mode here).
 """
 
 import jax.numpy as jnp
@@ -13,6 +14,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import mscm as M
 from repro.core.chunked import ChunkedLayer, ColumnELLLayer
+from repro.kernels import ops
 from repro.kernels import ref as ref_lib
 from repro.sparse import random_sparse_csc, random_sparse_csr
 
@@ -35,8 +37,9 @@ def _all_variants(ch, col, xi, xv, block_q, block_c, d, B):
     out = {
         "ref": ref_lib.mscm_ref(xd, rows, vals, bq, bc),
         "dense_lookup": M.mscm_dense_lookup(xd, rows, vals, bq, bc),
-        "searchsorted": M.mscm_searchsorted(
-            jnp.asarray(xi), jnp.asarray(xv), rows, vals, bq, bc, d
+        "grouped": ops.mscm_pallas_grouped(
+            jnp.asarray(xi), jnp.asarray(xv), d, rows, vals, bq, bc,
+            interpret=True,
         ),
         "vanilla": M.vanilla_columns(
             jnp.asarray(xi), jnp.asarray(xv),

@@ -60,7 +60,7 @@ def _assert_bitwise(planner, tree, xi, xv, beam, topk, method, score_mode, qt=8)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("method", [
-    "vanilla", "mscm_dense", "mscm_searchsorted", "mscm_pallas_grouped",
+    "vanilla", "mscm_dense", "mscm_pallas_grouped",
 ])
 @pytest.mark.parametrize("n_partitions", [2, 4])
 def test_partitioned_bitwise_every_method(tree_and_queries, method, n_partitions):
@@ -116,8 +116,8 @@ def test_deeper_split_level(tree_and_queries):
     idx = partition_tree(tree, 4, level=2)
     assert idx.level == 2
     assert idx.head.depth == 2
-    pl = ScatterGatherPlanner(idx, beam=6, topk=5, method="mscm_searchsorted")
-    _assert_bitwise(pl, tree, xi, xv, 6, 5, "mscm_searchsorted", "prod")
+    pl = ScatterGatherPlanner(idx, beam=6, topk=5, method="mscm_pallas_grouped")
+    _assert_bitwise(pl, tree, xi, xv, 6, 5, "mscm_pallas_grouped", "prod")
 
 
 # ---------------------------------------------------------------------------
@@ -132,7 +132,7 @@ if HAVE_HYPOTHESIS:
         qt=st.sampled_from([4, 8]),
         score_mode=st.sampled_from(["prod", "logsum"]),
         method=st.sampled_from(
-            ["mscm_dense", "mscm_searchsorted", "mscm_pallas_grouped"]
+            ["vanilla", "mscm_dense", "mscm_pallas_grouped"]
         ),
         seed=st.integers(0, 2**16),
     )

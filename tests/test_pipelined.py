@@ -53,7 +53,7 @@ def _assert_bitwise(got, ref):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("method", [
-    "vanilla", "mscm_dense", "mscm_searchsorted", "mscm_pallas_grouped",
+    "vanilla", "mscm_dense", "mscm_pallas_grouped",
 ])
 @pytest.mark.parametrize("n_partitions", [2, 4])
 def test_pipelined_bitwise_every_method(tree_and_queries, method, n_partitions):
@@ -129,9 +129,9 @@ def test_pipelined_deeper_split_level(tree_and_queries):
     tree, xi, xv = tree_and_queries
     idx = partition_tree(tree, 4, level=2)
     pl = ScatterGatherPlanner(
-        idx, beam=6, topk=5, method="mscm_searchsorted", sync="pipelined"
+        idx, beam=6, topk=5, method="mscm_pallas_grouped", sync="pipelined"
     )
-    ref = tree.infer(xi, xv, beam=6, topk=5, method="mscm_searchsorted")
+    ref = tree.infer(xi, xv, beam=6, topk=5, method="mscm_pallas_grouped")
     _assert_bitwise(pl.infer(xi, xv), ref)
 
 
@@ -175,7 +175,7 @@ if HAVE_HYPOTHESIS:
         qt=st.sampled_from([4, 8]),
         score_mode=st.sampled_from(["prod", "logsum"]),
         method=st.sampled_from(
-            ["mscm_dense", "mscm_searchsorted", "mscm_pallas_grouped"]
+            ["vanilla", "mscm_dense", "mscm_pallas_grouped"]
         ),
         seed=st.integers(0, 2**16),
     )

@@ -25,7 +25,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
-from repro.kernels.mscm_kernel import mscm_pregather
 from repro.quant.kernels import mscm_grouped_q_level
 
 D = 135_909          # amazon-670k feature dimension
@@ -189,15 +188,3 @@ def test_tree_infer_reads_tiles_in_place(one_chip, monkeypatch):
         assert not _tile_copies(text, c, B, R), c
     tile_bytes = C_LEAF * B * R * 4
     assert compiled.memory_analysis().temp_size_in_bytes < tile_bytes
-
-
-def test_pregather_compiles_for_v5e(one_chip):
-    def level(xg, vals, bc):
-        return mscm_pregather(xg, vals, bc, interpret=False)
-
-    s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
-    compiled = jax.jit(level).lower(
-        s((A, R), jnp.float32), s((C, B, R), jnp.float32),
-        s((A,), jnp.int32),
-    ).compile()
-    _assert_kernel(compiled)

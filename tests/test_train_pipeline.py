@@ -81,7 +81,7 @@ def test_serving_engine_modes(trained):
 def test_serving_methods_agree(trained):
     ds, model = trained
     outs = {}
-    for method in ("vanilla", "mscm_dense", "mscm_searchsorted", "mscm_pallas"):
+    for method in ("vanilla", "mscm_dense", "mscm_pallas_grouped"):
         eng = XMRServingEngine(
             model.tree, ServeConfig(beam=16, topk=5, ell_width=64, method=method)
         )

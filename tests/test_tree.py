@@ -1,9 +1,9 @@
 """End-to-end tree inference: Algorithm 1 with every masked-matmul method.
 
 Pins the paper's exactness claim at the system level under the contract
-stated above ``core.tree.METHODS``: vanilla, MSCM (both iterators) and the
-Pallas kernels return identical labels, and scores that agree to a few f32
-ulp (each method sums the chunk dot products in its own order).
+stated above ``core.tree.METHODS``: vanilla, dense-lookup MSCM and the
+grouped Pallas kernel return identical labels, and scores that agree to a
+few f32 ulp (each method sums the chunk dot products in its own order).
 """
 
 import jax.numpy as jnp
@@ -164,33 +164,37 @@ def test_grouped_fully_jitted(small_tree):
         assert _tree_infer._cache_size() == size_after_first
 
 
-def test_grouped_program_holds_no_dense_table(small_tree):
-    """The grouped program builds its query tiles from the ELL queries: the
-    lowered ``_tree_infer`` holds no f32 [n, d+1] table and no scatter. The
-    dense-lookup program, which keeps its table, is the control."""
+@pytest.mark.parametrize(
+    "method", [m for m in METHODS if not m.endswith("_q")]
+)
+def test_grouped_program_holds_no_dense_table(small_tree, method):
+    """The lowered ``_tree_infer`` holds the f32 [n, d+1] query table, and
+    the scatter that fills it, exactly where ``reads_dense_table`` says so:
+    the grouped program builds its query tiles from the ELL queries and the
+    vanilla one intersects per column. The planner builds a partition's
+    table on the same predicate."""
     import re
 
     import jax
 
-    from repro.core.tree import _tree_infer
+    from repro.core.tree import _tree_infer, reads_dense_table
+    from repro.index import ScatterGatherPlanner, partition_tree
 
     tree, ws, x, xi, xv = small_tree
     n, d = xi.shape[0], tree.d
     table = re.compile(rf"tensor<{n}x{d + 1}xf32>")
-
-    def lowered(method):
-        return jax.jit(
-            lambda a, b: _tree_infer(
-                tuple(tree.layers), tree.n_cols, tree.branching, d, a, b,
-                beam=4, topk=3, method=method, score_mode="prod", qt=4,
-            )
-        ).lower(xi, xv).as_text()
-
-    dense = lowered("mscm_dense")
-    assert table.search(dense) and "stablehlo.scatter" in dense
-    grouped = lowered("mscm_pallas_grouped")
-    assert not table.search(grouped)
-    assert "scatter" not in grouped
+    text = jax.jit(
+        lambda a, b: _tree_infer(
+            tuple(tree.layers), tree.n_cols, tree.branching, d, a, b,
+            beam=4, topk=3, method=method, score_mode="prod", qt=4,
+        )
+    ).lower(xi, xv).as_text()
+    dense = reads_dense_table(method)
+    assert dense == (method == "mscm_dense")
+    assert bool(table.search(text)) == dense
+    assert ("scatter" in text) == dense
+    planner = ScatterGatherPlanner(partition_tree(tree, 2), method=method)
+    assert planner._needs_dense == dense
 
 
 def test_tiles_padded_to_lanes(small_tree):
